@@ -90,6 +90,8 @@ def collect_rows() -> list:
 
 
 def main(argv=None) -> None:
+    from repro.runtime.compile_cache import setup_compile_cache
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out-dir", type=Path, default=ROOT,
                     help="where BENCH_<sha>.json is written")

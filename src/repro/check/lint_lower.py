@@ -1,10 +1,12 @@
 """Lint the Pallas launch parameters a schedule carries in ``lowered``.
 
 Independent re-statement of the TPU launch contract the kernels in
-``repro.kernels`` assume (sublane-aligned power-of-two blocks under the
-VMEM caps, blocks never exceeding their tensor extents, every ragged
-final block paired with an in-kernel mask record) — checked against the
-``Layer`` shapes alone, without calling ``search.lower``.  A block that
+``repro.kernels`` assume (row blocks aligned to the 8-row sublane, lane
+blocks a multiple of the 128-wide lane or the whole extent — the rule
+the TPU's Pallas lowering enforces — all under the VMEM caps, blocks
+never exceeding their tensor extents, every ragged final block paired
+with an in-kernel mask record) — checked against the ``Layer`` shapes
+alone, without calling ``search.lower``.  A block that
 silently stopped dividing its extent, a dropped ragged/mask entry, or a
 stale remainder all surface here as findings.
 """
@@ -17,6 +19,7 @@ from repro.core.workload import Layer
 from repro.check.schedule import Finding
 
 _SUBLANE = 8
+_LANE = 128
 _MAX_BLOCK_M = 256      # pixel/row blocks: fused_ibn / matmul_ln / flash
 _MAX_BLOCK_F = 512      # feature/reduction blocks: fused_ibn / matmul_ln
 
@@ -31,10 +34,13 @@ def _pow2_floor(v: int) -> int:
 
 
 def _check_block(key: str, param: str, block, extent: int, cap: int,
-                 findings: List[Finding]) -> Optional[int]:
-    """One launch block: an integer power of two, within the VMEM cap,
-    never past the (padded) extent, sublane-sized unless the extent
-    itself is sub-sublane.  Returns the block when usable."""
+                 findings: List[Finding], *,
+                 lane: bool = False) -> Optional[int]:
+    """One launch block: an integer within the VMEM cap, never past the
+    (padded) extent.  A ``lane`` block (the last dim of its operand
+    block) is a multiple of 128 or the whole extent; a row block is a
+    multiple of the 8-row sublane unless the extent itself is
+    sub-sublane.  Returns the block when usable."""
     try:
         b = int(block)
     except (TypeError, ValueError):
@@ -45,9 +51,11 @@ def _check_block(key: str, param: str, block, extent: int, cap: int,
         findings.append(Finding("lint.block_range", key,
                                 f"{param} = {b} < 1"))
         return None
-    if b & (b - 1):
-        findings.append(Finding("lint.block_pow2", key,
-                                f"{param} = {b} is not a power of two"))
+    if lane and b % _LANE and b != extent:
+        findings.append(Finding(
+            "lint.block_lane", key,
+            f"{param} = {b} is neither a multiple of the {_LANE}-wide"
+            f" lane nor the extent {extent}"))
     if b > cap:
         findings.append(Finding("lint.block_cap", key,
                                 f"{param} = {b} exceeds the {cap} cap"))
@@ -56,11 +64,12 @@ def _check_block(key: str, param: str, block, extent: int, cap: int,
             "lint.block_extent", key,
             f"{param} = {b} exceeds its extent {extent}: the grid"
             " would launch fully-padded blocks"))
-    if b < _SUBLANE and b != _pow2_floor(max(1, extent)):
+    if not lane and b % _SUBLANE and not (
+            b < _SUBLANE and b == _pow2_floor(max(1, extent))):
         findings.append(Finding(
             "lint.block_sublane", key,
-            f"{param} = {b} is below the {_SUBLANE}-row sublane but the"
-            f" extent {extent} allows a larger block"))
+            f"{param} = {b} is not a multiple of the {_SUBLANE}-row"
+            f" sublane and the extent {extent} allows one"))
     return b
 
 
@@ -126,7 +135,7 @@ def lint_doc(doc: dict,
             bm = _check_block(key, "block_m", val.get("block_m"), m,
                               _MAX_BLOCK_M, findings)
             bf = _check_block(key, "block_f", val.get("block_f"), f,
-                              _MAX_BLOCK_F, findings)
+                              _MAX_BLOCK_F, findings, lane=True)
             _check_ragged(key, "m", bm, m, ragged, findings)
             _check_ragged(key, "f", bf, f, ragged, findings)
         elif kernel == "matmul_ln":
@@ -140,7 +149,7 @@ def lint_doc(doc: dict,
             bm = _check_block(key, "block_m", val.get("block_m"), m,
                               _MAX_BLOCK_M, findings)
             bk = _check_block(key, "block_k", val.get("block_k"), red,
-                              _MAX_BLOCK_F, findings)
+                              _MAX_BLOCK_F, findings, lane=True)
             _check_ragged(key, "m", bm, m, ragged, findings)
             _check_ragged(key, "k", bk, red, ragged, findings)
         elif kernel == "flash_attention":

@@ -162,11 +162,13 @@ def _mut_oversize_block(doc, layers):
     return False
 
 
-def _mut_non_pow2_block(doc, layers):
-    for param in ("block_m", "block_q"):
-        v = _lowered_with(doc, param)
-        if v is not None:
-            v[param] = 24
+def _mut_lane_misaligned_block(doc, layers):
+    """A 64-wide lane block on an operand wider than 64: the block the
+    TPU lowering refuses (neither a 128 multiple nor the extent)."""
+    widths = {l.name: l.k for l in layers}
+    for key, v in (doc.get("lowered") or {}).items():
+        if "block_f" in v and widths.get(key.split(" + ")[0], 0) > 64:
+            v["block_f"] = 64
             return True
     return False
 
@@ -265,8 +267,9 @@ MUTATIONS: Tuple[Mutation, ...] = (
              _mut_stale_ragged),
     Mutation("oversize_block", "edgenext-s",
              "launch block past the VMEM cap", _mut_oversize_block),
-    Mutation("non_pow2_block", "edgenext-s",
-             "launch block not a power of two", _mut_non_pow2_block),
+    Mutation("lane_misaligned_block", "edgenext-s",
+             "lane block neither a 128 multiple nor the extent",
+             _mut_lane_misaligned_block),
     Mutation("tamper_latency", "edgenext-s",
              "headline latency inflated", _mut_tamper_latency),
     Mutation("tamper_energy", "edgenext-s",

@@ -34,7 +34,7 @@ from repro.core.memory import MemoryHierarchy
 from repro.core.workload import (MAC_OPS, SCAN, Layer, scan_macs,
                                  scan_state_bytes)
 
-KNOWN_VERSIONS = (6,)
+KNOWN_VERSIONS = (7,)
 
 _DIM_NAMES = ("b", "k", "c", "ox", "oy", "fx", "fy")
 _OPERANDS = ("input", "weight", "output")

@@ -15,7 +15,7 @@ one (H+fy-1, W+fx-1, bc) padded input block and produces (H, W, bc).
 BlockSpecs:
   x   : (1, H+fy-1, W+fx-1, bc) at (b, 0, 0, c)   — pre-padded input
   w   : (fy, fx, bc)            at (0, 0, c)
-  bias: (bc,)                   at (c,)
+  bias: (1, bc)                 at (0, c)   — 2-D so the lane block tiles
   out : (1, H, W, bc)           at (b, 0, 0, c)
 """
 from __future__ import annotations
@@ -35,7 +35,7 @@ def _dw_kernel(x_ref, w_ref, b_ref, o_ref, *, fy: int, fx: int, H: int,
         for dx in range(fx):
             tap = x[dy:dy + H, dx:dx + W, :].astype(jnp.float32)
             acc += tap * w_ref[dy, dx, :].astype(jnp.float32)
-    o_ref[0] = (acc + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+    o_ref[0] = (acc + b_ref[0].astype(jnp.float32)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -58,9 +58,9 @@ def depthwise_conv2d(x: jax.Array, w: jax.Array, b: jax.Array, *,
             pl.BlockSpec((1, H + fy - 1, W + fx - 1, bc),
                          lambda bi, ci: (bi, 0, 0, ci)),
             pl.BlockSpec((fy, fx, bc), lambda bi, ci: (0, 0, ci)),
-            pl.BlockSpec((bc,), lambda bi, ci: (ci,)),
+            pl.BlockSpec((1, bc), lambda bi, ci: (0, ci)),
         ],
         out_specs=pl.BlockSpec((1, H, W, bc), lambda bi, ci: (bi, 0, 0, ci)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, C), x.dtype),
         interpret=interpret,
-    )(xp, w, b)
+    )(xp, w, b.reshape(1, C))

@@ -33,6 +33,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# f32 operands contract in full f32: Mosaic's default precision rounds
+# them to bf16 (max|d| ~1e-2 against the f32 oracle on a TPU v5e)
+HIGHEST = jax.lax.Precision.HIGHEST
+
 NEG_INF = -1e30
 
 
@@ -50,7 +54,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     i = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale              # [bq, D]
     k = k_ref[0].astype(jnp.float32)                      # [bk, D]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # [bq, bk]
+    s = jnp.dot(q, k.T, precision=HIGHEST,
+                preferred_element_type=jnp.float32)   # [bq, bk]
 
     q_pos = i * bq + jax.lax.iota(jnp.int32, bq)[:, None]
     k_pos = j * bk + jax.lax.iota(jnp.int32, bk)[None, :]
@@ -70,6 +75,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
         p.astype(v_ref.dtype), v_ref[0],
+        precision=HIGHEST,
         preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 

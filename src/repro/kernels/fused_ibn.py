@@ -35,6 +35,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# f32 operands contract in full f32: Mosaic's default precision rounds
+# them to bf16 (max|d| ~1e-2 against the f32 oracle on a TPU v5e)
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _act(name: str, x: jax.Array) -> jax.Array:
     if name == "gelu":
@@ -66,9 +70,11 @@ def _ibn_kernel(x_ref, w1_ref, w2_ref, o_ref, acc_ref, *, activation: str,
 
     x = x_ref[...]
     # T tile: produced in VMEM, consumed immediately, never written to HBM
-    t = jnp.dot(x, w1_ref[...], preferred_element_type=jnp.float32)
+    t = jnp.dot(x, w1_ref[...], precision=HIGHEST,
+                preferred_element_type=jnp.float32)
     t = _mask_ragged_f(_act(activation, t), j, bf, valid_f)
     acc_ref[...] += jnp.dot(t.astype(x.dtype), w2_ref[...],
+                            precision=HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(j == n_f - 1)
@@ -85,10 +91,13 @@ def _ibn_gated_kernel(x_ref, w1_ref, wg_ref, w2_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]
-    up = jnp.dot(x, w1_ref[...], preferred_element_type=jnp.float32)
-    gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+    up = jnp.dot(x, w1_ref[...], precision=HIGHEST,
+                 preferred_element_type=jnp.float32)
+    gate = jnp.dot(x, wg_ref[...], precision=HIGHEST,
+                   preferred_element_type=jnp.float32)
     t = _mask_ragged_f(_act(activation, gate) * up, j, bf, valid_f)
     acc_ref[...] += jnp.dot(t.astype(x.dtype), w2_ref[...],
+                            precision=HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(j == n_f - 1)

@@ -34,6 +34,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# f32 operands contract in full f32: Mosaic's default precision rounds
+# them to bf16 (max|d| ~1e-2 against the f32 oracle on a TPU v5e)
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _matmul_ln_kernel(x_ref, w_ref, b_ref, g_ref, o_ref, out_ref, acc_ref,
                       *, n_k: int, bk: int, valid_k: int, eps: float):
@@ -49,6 +53,7 @@ def _matmul_ln_kernel(x_ref, w_ref, b_ref, g_ref, o_ref, out_ref, acc_ref,
         k_idx = k * bk + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
         x = jnp.where(k_idx < valid_k, x, 0)
     acc_ref[...] += jnp.dot(x, w_ref[...],
+                            precision=HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)

@@ -24,6 +24,9 @@ from repro.kernels import (depthwise_conv as _dw, flash_attention as _fa,
                            rwkv_chunk as _wkv)
 
 
+_LANE = 128      # TPU lane width: the last block dim's granularity
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -101,12 +104,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def depthwise_conv2d(x: jax.Array, w: jax.Array, b: jax.Array, *,
                      block_c: int = 128,
                      interpret: Optional[bool] = None) -> jax.Array:
+    """Channels ride the lane axis, so the channel block is the whole C
+    or a multiple of the 128-wide lane; C is zero-padded to a block
+    multiple (padded channels are independent and sliced off)."""
     interp = (not _on_tpu()) if interpret is None else interpret
     C = x.shape[-1]
-    bc = min(block_c, C)
-    while C % bc:
-        bc //= 2
-    return _dw.depthwise_conv2d(x, w, b, block_c=bc, interpret=interp)
+    bc = max(_LANE, block_c // _LANE * _LANE)
+    if bc >= C:
+        bc = C
+    out = _dw.depthwise_conv2d(_pad_to(x, 3, bc), _pad_to(w, 2, bc),
+                               _pad_to(b, 0, bc), block_c=bc,
+                               interpret=interp)
+    return out[..., :C]
 
 
 def wkv_chunked(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,

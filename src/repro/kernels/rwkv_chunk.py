@@ -14,7 +14,9 @@ across chunk steps (TPU grids execute sequentially).  Decay exponents are
 BlockSpecs:
   r,k,w : (1, C, K) at (bh, c, 0)
   v     : (1, C, V) at (bh, c, 0)
-  u     : (1, K)    at (bh, 0)     — per-head bonus, caller-expanded
+  u     : (1, 1, K) at (bh, 0, 0)  — per-head bonus, caller-expanded; the
+                                   unit axis keeps the block's last two
+                                   dims equal to the array's
   out   : (1, C, V) at (bh, c, 0)
   state : (1, K, V) at (bh, 0, 0)  — final state output
 """
@@ -26,6 +28,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# f32 operands contract in full f32: Mosaic's default precision rounds
+# them to bf16 (max|d| ~1e-2 against the f32 oracle on a TPU v5e)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref,
@@ -40,7 +46,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref,
     kc = k_ref[0].astype(jnp.float32)
     vc = v_ref[0].astype(jnp.float32)              # [C, V]
     wc = w_ref[0].astype(jnp.float32)              # [C, K] log-decay <= 0
-    u = u_ref[0].astype(jnp.float32)               # [K]
+    u = u_ref[0].astype(jnp.float32)               # [1, K]
 
     if valid_t % C:
         # ragged T: zero the padded tail of the final chunk so it is
@@ -53,22 +59,29 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref,
         kc = jnp.where(live, kc, 0.0)
         wc = jnp.where(live, wc, 0.0)
 
-    b = jnp.cumsum(wc, axis=0)                     # [C, K]
+    # running log-decay as a lower-triangular matmul (the TPU lowering
+    # has no cumsum)
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    b = jnp.dot(jnp.where(row >= col, 1.0, 0.0), wc,
+                precision=HIGHEST,
+                preferred_element_type=jnp.float32)       # [C, K]
     b_prev = b - wc
     S = state_ref[...]
 
     # inter-chunk: r_t decayed to the chunk start, applied to carried state
     inter = jnp.dot(rc * jnp.exp(b_prev), S,
+                    precision=HIGHEST,
                     preferred_element_type=jnp.float32)        # [C, V]
 
     # intra-chunk scores A[t,s] = sum_k r_t k_s exp(b_{t-1} - b_s), s < t
     expo = jnp.exp(jnp.clip(b_prev[:, None, :] - b[None, :, :],
                             max=0.0))              # [C, C, K]
-    A = jnp.einsum("tk,sk,tsk->ts", rc, kc, expo)
-    tri = jnp.tril(jnp.ones((C, C), jnp.bool_), k=-1)
-    A = jnp.where(tri, A, 0.0)
-    diag = jnp.sum(rc * u[None, :] * kc, axis=-1)  # [C]
-    intra = jnp.dot(A, vc, preferred_element_type=jnp.float32) \
+    A = jnp.sum(rc[:, None, :] * kc[None, :, :] * expo, axis=-1)
+    A = jnp.where(row > col, A, 0.0)
+    diag = jnp.sum(rc * u * kc, axis=-1)           # [C]
+    intra = jnp.dot(A, vc, precision=HIGHEST,
+                    preferred_element_type=jnp.float32) \
         + diag[:, None] * vc
 
     o_ref[0] = (inter + intra).astype(o_ref.dtype)
@@ -77,7 +90,8 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref,
     b_end = b[-1:, :]                              # [1, K]
     k_dec = kc * jnp.exp(b_end - b)
     state_ref[...] = jnp.exp(b_end[0])[:, None] * S + jnp.dot(
-        k_dec.T, vc, preferred_element_type=jnp.float32)
+        k_dec.T, vc, precision=HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(c == n_chunks - 1)
     def _done():
@@ -115,7 +129,7 @@ def wkv_chunked(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
             pl.BlockSpec((1, C, K), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, C, V), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, C, K), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, K), lambda bh, c: (bh, 0)),
+            pl.BlockSpec((1, 1, K), lambda bh, c: (bh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, C, V), lambda bh, c: (bh, c, 0)),
@@ -127,5 +141,5 @@ def wkv_chunked(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u)
+    )(r, k, v, logw, u.reshape(BH, 1, K))
     return out[:, :T], state

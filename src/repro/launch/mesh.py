@@ -3,13 +3,15 @@
 Defined as FUNCTIONS (never module-level constants) so importing this
 module never touches jax device state — the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
-init, and smoke tests must keep seeing 1 device.
+init, and smoke tests must keep seeing 1 device.  Every axis is
+``AxisType.Auto``: the sharding rules in ``runtime.sharding`` place
+arrays with ``NamedSharding`` and let the compiler propagate the rest.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -24,7 +26,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"mesh {shape} needs {n} devices, found {len(devices)} — set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax (launch/dryrun.py does this)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, model: int = 1) -> Mesh:
@@ -32,4 +35,5 @@ def make_host_mesh(*, model: int = 1) -> Mesh:
     n = len(jax.devices())
     data = n // model
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[: data * model])
+                         devices=jax.devices()[: data * model],
+                         axis_types=(AxisType.Auto,) * 2)

@@ -18,9 +18,11 @@ from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import actshard, get_module, params as param_lib
 from repro.runtime import (build_decode_step, build_prefill_step,
                            model_param_pspecs)
+from repro.runtime.compile_cache import setup_compile_cache
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

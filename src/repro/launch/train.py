@@ -24,10 +24,12 @@ from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import actshard, get_module, params as param_lib
 from repro.optim import AdamWState, adamw_init, warmup_cosine
 from repro.runtime import batch_pspecs, build_train_step, model_param_pspecs
+from repro.runtime.compile_cache import setup_compile_cache
 from repro.runtime.watchdog import StragglerWatchdog
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",

@@ -236,7 +236,9 @@ def sdta_block(bp: Params, x: jax.Array, heads: int, scales: int,
         outs = [splits[0]]
         prev = None
         for i, sp in enumerate(splits[1:]):
-            inp = sp if prev is None else sp + prev
+            # the remainder split can be narrower than the one before
+            # it (160 = 54 + 54 + 52): it adds the leading channels
+            inp = sp if prev is None else sp + prev[..., :sp.shape[-1]]
             prev = depthwise_conv2d(inp, bp["dw"][i]["w"].astype(dtype),
                                     bp["dw"][i]["b"].astype(dtype))
             outs.append(prev)

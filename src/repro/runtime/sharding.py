@@ -24,19 +24,11 @@ Pytree = Any
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
-    """Version-portable shard_map.
-
-    jax >= 0.6 exposes ``jax.shard_map`` (replication checking flag
-    ``check_vma``); 0.4.x only has ``jax.experimental.shard_map`` with
-    ``check_rep``.  Both checks are disabled — the callers do their own
-    psum bookkeeping the checker cannot follow.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking (``check_vma``) off —
+    the callers do their own psum bookkeeping the checker cannot
+    follow."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:

@@ -51,7 +51,11 @@ from repro.core.workload import Layer
 #     chunk length + state residence level in ``tiles`` and a state
 #     placement entry, and the fusion DP prices carry-state traffic;
 #     schedules for scan-free workloads change only in this version tag
-SEARCH_VERSION = 6
+# v7: lane-axis launch blocks (``block_f``, matmul_ln ``block_k``) in
+#     ``lowered`` are 128-lane multiples or the whole extent — the blocks
+#     the TPU's Pallas lowering accepts; searched tiles and costs are
+#     unchanged, so older artifacts differ only in ``lowered``
+SEARCH_VERSION = 7
 
 
 def schedule_key(layers: List[Layer], hw: HWSpec,

@@ -152,10 +152,10 @@ def _schedule_variants(layers: List[Layer], variants: Sequence[HWSpec],
                 raise ValueError("parallel sweeps cannot share a caller-"
                                  "supplied memo across processes; drop "
                                  "memo= or run serially")
-            from concurrent.futures import ProcessPoolExecutor
+            from repro.search.pool import cpu_process_pool
             act = obs.current()
             base = act.now() if act is not None else 0.0
-            with ProcessPoolExecutor(max_workers=parallel) as ex:
+            with cpu_process_pool(parallel) as ex:
                 results = list(ex.map(
                     _schedule_variant,
                     [(layers, hw, workload, dedup, spatial_mode,

@@ -8,9 +8,12 @@ drives actual launches:
   MAC + fused LN     -> kernels.ops.matmul_ln   (block_m, block_k)
   attention matmuls  -> kernels.ops.flash_attention (block_q, block_k)
 
-Abstract tile sizes are snapped to TPU-friendly blocks: powers of two,
-multiples of the 8-row sublane where the extent allows, clamped to the
-tensor extents.  A block is NOT forced to divide its extent: imperfect
+Abstract tile sizes are snapped to blocks the TPU's Pallas lowering
+accepts: row blocks are powers of two and multiples of the 8-row sublane
+where the extent allows; lane-axis blocks (``block_f``, matmul_ln's
+``block_k``) are power-of-two multiples of the 128-wide lane, or the
+whole extent when it is at most one lane wide.  Blocks are clamped to
+the tensor extents.  A block is NOT forced to divide its extent: imperfect
 blocks are first-class — ``_snap`` reports the ragged final block
 explicitly, the ``ops`` wrappers pad the operands to a block multiple,
 and the kernels mask the padded region in-kernel (edge predication), so
@@ -32,6 +35,7 @@ from repro.search import tiler
 # VMEM is ~16 MB/core; keep resident blocks far below it and aligned to
 # the f32 (8, 128) tile granularity where the extents allow.
 _SUBLANE = 8
+_LANE = 128
 _MAX_BLOCK_M = 256
 _MAX_BLOCK_F = 512
 
@@ -59,6 +63,18 @@ def _snap(v: int, lo: int, hi: int, extent: int) -> Tuple[int, int]:
     b = _pow2_floor(max(1, max(lo, min(v, hi))))
     b = _pow2_floor(max(1, min(b, extent)))
     return b, extent % b
+
+
+def _snap_lane(v: int, hi: int, extent: int) -> Tuple[int, int]:
+    """Lane-axis block: a power-of-two multiple of the 128-wide lane in
+    [128, hi] near v, or the whole extent when it is at most one lane
+    wide.  The TPU lowering refuses any other lane block (it must be a
+    multiple of 128 or equal the array's dimension), so a modelled tile
+    narrower than a lane launches one lane wide."""
+    extent = max(1, extent)
+    if extent <= _LANE:
+        return extent, 0
+    return _snap(v, _LANE, hi, extent)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,14 +109,14 @@ def lower_ibn(expand: Layer, project: Layer, *, local_buffer: int,
             #                 its padded extent with ragged metadata
             #                 that contradicts the actual launch
             bm, rm = _snap(_SUBLANE, _SUBLANE, _MAX_BLOCK_M, n_pix)
-            bf, rf = _snap(128, _SUBLANE, 128, F)
+            bf, rf = _snap_lane(_LANE, _LANE, F)
             return LoweredKernel("fused_ibn",
                                  (expand.name, project.name),
                                  {"block_m": bm, "block_f": bf},
                                  {"m": rm, "f": rf})
         tile_x, tile_c = ft.tile_x, ft.tile_c
     bm, rm = _snap(tile_x, _SUBLANE, _MAX_BLOCK_M, n_pix)
-    bf, rf = _snap(tile_c, _SUBLANE, _MAX_BLOCK_F, F)
+    bf, rf = _snap_lane(tile_c, _MAX_BLOCK_F, F)
     return LoweredKernel("fused_ibn", (expand.name, project.name),
                          {"block_m": bm, "block_f": bf},
                          {"m": rm, "f": rf})
@@ -110,12 +126,13 @@ def lower_matmul_ln(mac: Layer, norm: Layer, *, tile_x: int,
                     tile_c: int) -> LoweredKernel:
     """MAC layer with a fused trailing LayerNorm -> matmul_ln blocks.
     block_m covers the pixel tile (rows resident for the stats pass);
-    block_k covers the reduction tile.  block_k need not divide K — the
-    kernel zero-masks the ragged final reduction block in-kernel."""
+    block_k covers the reduction tile on the lane axis of x.  block_k
+    need not divide K — the kernel zero-masks the ragged final reduction
+    block in-kernel."""
     n_pix = mac.b * mac.ox * mac.oy
     red = mac.c * mac.fx * mac.fy
     bm, rm = _snap(tile_x, _SUBLANE, _MAX_BLOCK_M, n_pix)
-    bk, rk = _snap(tile_c, _SUBLANE, _MAX_BLOCK_F, red)
+    bk, rk = _snap_lane(tile_c, _MAX_BLOCK_F, red)
     return LoweredKernel("matmul_ln", (mac.name, norm.name),
                          {"block_m": bm, "block_k": bk},
                          {"m": rm, "k": rk})

@@ -44,7 +44,8 @@ real schedule once the fault clears) and their results carry
 returned ``Schedule``.
 
 ``warm()`` fans the (workload x batch) grid out over a process pool
-(the same ``--jobs`` shape as the DSE sweeps); each worker runs
+(the same ``--jobs`` shape as the DSE sweeps, spawned CPU-only workers
+from ``search.pool``); each worker runs
 ``cached_search`` against the shared cache dir — the per-key store
 claim in ``search.cache`` guarantees exactly one artifact write per key
 no matter how the pool races — and the parent then faults every
@@ -553,14 +554,14 @@ class ServeStore:
                       todo=len(todo)):
             searched = 0
             if jobs > 1 and todo:
-                from concurrent.futures import ProcessPoolExecutor
+                from repro.search.pool import cpu_process_pool
                 monkey = chaos_mod.current()
                 work = [(n, self.hw, self.cache_dir, self.tile_mode,
                          self.spatial_mode,
                          monkey.should("worker_crash") if monkey
                          else False)
                         for n in todo.values()]
-                with ProcessPoolExecutor(max_workers=jobs) as ex:
+                with cpu_process_pool(jobs) as ex:
                     futures = [ex.submit(_warm_worker, a) for a in work]
                     for fut in futures:
                         try:

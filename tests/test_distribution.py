@@ -39,7 +39,8 @@ def test_sharded_moe_matches_plain_multidevice():
         # model=4 divides the 4 padded experts of the reduced configs;
         # data=1 keeps per-shard capacity equal to the global capacity so
         # the comparison is exact
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        mesh = jax.make_mesh((1, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         for arch in ('qwen3-moe-30b-a3b', 'qwen2-moe-a2.7b'):
             cfg = reduced(get_config(arch))
             pr = P.init_params(jax.random.PRNGKey(0), L.moe_defs(cfg))
@@ -62,7 +63,8 @@ def test_sharded_moe_grads_multidevice():
         from repro.configs import get_config, reduced
         from repro.models import layers as L, params as P
         from repro.models.moe_sharded import moe_apply_sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = reduced(get_config('qwen3-moe-30b-a3b'))
         pr = P.init_params(jax.random.PRNGKey(0), L.moe_defs(cfg))
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
@@ -87,7 +89,8 @@ def test_train_step_on_2d_mesh_multidevice():
         from repro.optim import AdamWState, adamw_init, warmup_cosine
         from repro.runtime import (batch_pspecs, build_train_step,
                                    model_param_pspecs)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         actshard.set_mesh(mesh)
         cfg = reduced(get_config('h2o-danube-1.8b'))
         mod = get_module(cfg)

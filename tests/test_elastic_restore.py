@@ -27,7 +27,8 @@ def test_restore_across_meshes(tmp_path):
     defs = mod.param_defs(cfg)
 
     # write under a (2, 4) mesh
-    mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_a = jax.make_mesh((2, 4), ("data", "model"),
+                           axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ps_a = model_param_pspecs(cfg, mesh_a, defs)
     named_a = jax.tree.map(lambda s: NamedSharding(mesh_a, s), ps_a,
                            is_leaf=lambda x: isinstance(x, P))
@@ -36,7 +37,8 @@ def test_restore_across_meshes(tmp_path):
     save_checkpoint({str(repr(str(tmp_path)))}, 5, params)
 
     # restore under a (4, 2) mesh — different shard layout
-    mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_b = jax.make_mesh((4, 2), ("data", "model"),
+                           axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ps_b = model_param_pspecs(cfg, mesh_b, defs)
     named_b = jax.tree.map(lambda s: NamedSharding(mesh_b, s), ps_b,
                            is_leaf=lambda x: isinstance(x, P))

@@ -191,6 +191,21 @@ def test_edgenext_param_count_matches_published():
     assert abs(n / 1e6 - 5.6) < 0.2, n          # paper: ~5.6M
 
 
+@pytest.mark.parametrize("ibn_chunks", [0, 4])
+def test_edgenext_full_config_traces(ibn_chunks):
+    """The published EdgeNeXt-S widths trace end to end: stage 3's SDTA
+    splits 160 channels unevenly (54 + 54 + 52), which the reduced
+    config never exercises."""
+    defs = edgenext.param_defs(EDGE_FULL)
+    img = jax.ShapeDtypeStruct((1, EDGE_FULL.img_size, EDGE_FULL.img_size,
+                                EDGE_FULL.in_channels), jnp.float32)
+    out = jax.eval_shape(
+        lambda p, x: edgenext.forward(EDGE_FULL, p, x,
+                                      ibn_chunks=ibn_chunks),
+        P.abstract_params(defs), img)
+    assert out.shape == (1, EDGE_FULL.num_classes)
+
+
 @pytest.mark.slow
 def test_edgenext_forward_and_chunked_ibn():
     cfg = reduced_edgenext()

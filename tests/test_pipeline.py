@@ -28,7 +28,8 @@ def test_gpipe_matches_sequential():
         import jax, jax.numpy as jnp, numpy as np
         from jax import lax
         from repro.runtime.pipeline import gpipe, microbatch, split_stages
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        mesh = jax.make_mesh((1, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         L, D, B, M = 8, 16, 8, 4
         ks = jax.random.split(jax.random.PRNGKey(0), 2)
         W = jax.random.normal(ks[0], (L, D, D)) * (0.5 / D ** 0.5)

@@ -367,9 +367,9 @@ def test_schedule_json_roundtrip(tmp_path):
 def test_stale_v4_artifacts_rejected(tmp_path):
     """A SEARCH_VERSION=4 cache entry must never be replayed as a
     current result: load_schedule refuses it and cached_search
-    re-searches.  (v6: chunked-recurrence SCAN op class.)"""
+    re-searches.  (v7: lane-aligned launch blocks in ``lowered``.)"""
     from repro.search.cache import SEARCH_VERSION, schedule_key
-    assert SEARCH_VERSION == 6
+    assert SEARCH_VERSION == 7
     wl = edgenext_workload(reduced_edgenext())
     key = schedule_key(wl, HW)
     path = tmp_path / f"edgenext-reduced-{key}.json"
@@ -453,12 +453,23 @@ def test_cli_smoke(tmp_path):
 
 
 def test_lowered_params_well_formed():
+    """Every launch block is one the TPU lowering accepts: lane blocks
+    (fused_ibn block_f, matmul_ln block_k) a multiple of 128 or the
+    whole extent, row blocks a power of two."""
     assert SCHED.lowered, "EdgeNeXt must lower at least the IBN kernels"
+    by_name = {l.name: l for l in WL}
     for name, lk in SCHED.lowered.items():
         assert lk["kernel"] in ("fused_ibn", "matmul_ln",
                                 "flash_attention", "rwkv_chunk"), name
+        head = by_name[name.split(" + ")[0]]
+        lane, ext = {"fused_ibn": ("block_f", head.k),
+                     "matmul_ln": ("block_k",
+                                   head.c * head.fx * head.fy),
+                     }.get(lk["kernel"], (None, 0))
         for k, v in lk.items():
-            if k.startswith("block_"):
+            if k == lane:
+                assert v % 128 == 0 or v == ext, (name, k, v, ext)
+            elif k.startswith("block_"):
                 assert v >= 1 and (v & (v - 1)) == 0, (name, k, v)
 
 

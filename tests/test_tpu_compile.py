@@ -1,0 +1,133 @@
+"""Compile the served path's Pallas kernels for a described TPU v5e.
+
+No chip is needed: the TPU compiler is installed, and it compiles for a
+chip that is described, not attached.  It refuses what interpret mode
+accepts — a lane block that is neither a multiple of 128 nor the full
+extent, a primitive Mosaic cannot lower — so each case here compiles one
+launch at EdgeNeXt-S / rwkv6 width, with the launch parameters that
+``search.lower`` emits for the searched schedule, and asserts that the
+compiled program holds the Mosaic kernel (``tpu_custom_call``).
+
+The topology is described inside a fixture (never at import time): only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.costmodel import HWSpec
+from repro.core.workload import DWCONV
+from repro.kernels import ops
+from repro.search import auto_schedule, get_workload
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no TPU library: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compilation cache off:
+    an entry written for a described chip cannot be read back here."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def served():
+    """(layers by name, lowered launches) of the searched schedules."""
+    out = {}
+    for name in ("edgenext-s", "rwkv6"):
+        layers = get_workload(name)
+        sched = auto_schedule(layers, HWSpec(), workload=name)
+        out[name] = ({l.name: l for l in layers}, sched.lowered)
+    return out
+
+
+def _launch(served, workload, kernel, head):
+    """The lowered launch of ``kernel`` whose group starts at ``head``,
+    with its layers."""
+    by_name, lowered = served[workload]
+    for names, lk in lowered.items():
+        parts = names.split(" + ")
+        if lk["kernel"] == kernel and parts[0] == head:
+            return [by_name[p] for p in parts], lk
+    raise AssertionError(f"no {kernel} launch at {head} in {workload}")
+
+
+def _ibn(served, workload, head):
+    (expand, project), lk = _launch(served, workload, "fused_ibn", head)
+    m, d, f = expand.b * expand.ox * expand.oy, expand.c, expand.k
+    act = "relu2" if workload == "rwkv6" else "gelu"
+    return (functools.partial(ops.fused_ibn, activation=act,
+                              block_m=lk["block_m"], block_f=lk["block_f"],
+                              interpret=False),
+            [(m, d), (d, f), (f, project.k)])
+
+
+def _matmul_ln(served, workload, head):
+    (mac, _), lk = _launch(served, workload, "matmul_ln", head)
+    m, k, n = mac.b * mac.ox * mac.oy, mac.c * mac.fx * mac.fy, mac.k
+    return (functools.partial(ops.matmul_ln, block_m=lk["block_m"],
+                              block_k=lk["block_k"], interpret=False),
+            [(m, k), (k, n), (n,), (n,), (n,)])
+
+
+def _attention(served, workload, head):
+    (qk,), lk = _launch(served, workload, "flash_attention", head)
+    return (functools.partial(ops.flash_attention, causal=False,
+                              block_q=lk["block_q"], block_k=lk["block_k"],
+                              interpret=False),
+            [(1, qk.b, qk.ox, qk.c)] + [(1, qk.b, qk.k, qk.c)] * 2)
+
+
+def _wkv(served, workload, head):
+    (scan,), lk = _launch(served, workload, "rwkv_chunk", head)
+    bh, t, k, v = scan.b, scan.ox, scan.c, scan.k
+    return (functools.partial(ops.wkv_chunked, chunk=lk["chunk"],
+                              interpret=False),
+            [(bh, t, k), (bh, t, k), (bh, t, v), (bh, t, k), (bh, k)])
+
+
+def _depthwise(served, workload, head):
+    by_name, _ = served[workload]
+    dw = by_name[head]
+    assert dw.op == DWCONV
+    return (functools.partial(ops.depthwise_conv2d, interpret=False),
+            [(dw.b, dw.oy, dw.ox, dw.c), (dw.fy, dw.fx, dw.c), (dw.c,)])
+
+
+CASES = {
+    "fused_ibn-stage0": (_ibn, "edgenext-s", "s0.conv0.pw1"),
+    "fused_ibn-stage3": (_ibn, "edgenext-s", "s3.conv0.pw1"),
+    "matmul_ln-stage1": (_matmul_ln, "edgenext-s", "s1.sdta0.proj"),
+    "flash_attention-xca-stage1": (_attention, "edgenext-s", "s1.sdta0.qk"),
+    "depthwise-c160": (_depthwise, "edgenext-s", "s2.conv0.dw"),
+    "depthwise-c304": (_depthwise, "edgenext-s", "s3.conv0.dw"),
+    "rwkv_chunk": (_wkv, "rwkv6", "blk0.tmix.wkv"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, served):
+    build, workload, head = CASES[case]
+    fn, shapes = build(served, workload, head)
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
