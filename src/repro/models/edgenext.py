@@ -15,6 +15,14 @@ kernels/depthwise_conv.py).
 
 Simplifications vs the released checkpoints (documented in DESIGN.md):
 no stochastic depth, no positional embedding on the first SDTA block.
+
+Every op sits in a ``jax.named_scope`` spelled as the scheduler's layer
+(``repro.core.workload.edgenext_workload``): ``forward`` opens the block
+scope (``s1.conv0``, ``s2.sdta0``) and the block its layers' (``dw``,
+``pw1``, ...), so an op's HLO ``op_name`` holds ``s1.conv0/pw1``, which
+``repro.obs.layers`` reads back as the layer ``s1.conv0.pw1``.  A
+weight's cast sits in the scope of the layer that uses it.  Scopes are
+metadata: the compiled program is the same without them.
 """
 from __future__ import annotations
 
@@ -167,26 +175,39 @@ def _ibn_mlp(bp: Params, x: jax.Array, ibn_chunks: int = 0) -> jax.Array:
     the expanded channel dim, live tile bounded to d_ff/ibn_chunks).
     """
     dtype = x.dtype
-    w1 = bp["pw1_w"].astype(dtype)
-    b1 = bp["pw1_b"].astype(dtype)
-    w2 = bp["pw2_w"].astype(dtype)
-    b2 = bp["pw2_b"].astype(dtype)
+    with jax.named_scope("pw1"):
+        w1 = bp["pw1_w"].astype(dtype)
+        b1 = bp["pw1_b"].astype(dtype)
+    with jax.named_scope("pw2"):
+        w2 = bp["pw2_w"].astype(dtype)
+        b2 = bp["pw2_b"].astype(dtype)
     if ibn_chunks <= 1:
-        t = jax.nn.gelu(x @ w1 + b1, approximate=True)
-        return t @ w2 + b2
+        with jax.named_scope("pw1"):
+            t = x @ w1 + b1
+        with jax.named_scope("act"):
+            t = jax.nn.gelu(t, approximate=True)
+        with jax.named_scope("pw2"):
+            return t @ w2 + b2
     f = w1.shape[-1]
     assert f % ibn_chunks == 0
     tile = f // ibn_chunks
-    w1_t = w1.reshape(-1, ibn_chunks, tile).transpose(1, 0, 2)
-    b1_t = b1.reshape(ibn_chunks, tile)
-    w2_t = w2.reshape(ibn_chunks, tile, -1)
+    with jax.named_scope("pw1"):
+        w1_t = w1.reshape(-1, ibn_chunks, tile).transpose(1, 0, 2)
+        b1_t = b1.reshape(ibn_chunks, tile)
+    with jax.named_scope("pw2"):
+        w2_t = w2.reshape(ibn_chunks, tile, -1)
+        out0 = jnp.broadcast_to(b2, x.shape[:-1] + (w2.shape[-1],)
+                                ).astype(dtype)
 
     def step(acc, ws):
         w1c, b1c, w2c = ws
-        t = jax.nn.gelu(x @ w1c + b1c, approximate=True)
-        return acc + t @ w2c, None
+        with jax.named_scope("pw1"):
+            t = x @ w1c + b1c
+        with jax.named_scope("act"):
+            t = jax.nn.gelu(t, approximate=True)
+        with jax.named_scope("pw2"):
+            return acc + t @ w2c, None
 
-    out0 = jnp.broadcast_to(b2, x.shape[:-1] + (w2.shape[-1],)).astype(dtype)
     out, _ = lax.scan(step, out0, (w1_t, b1_t, w2_t))
     return out
 
@@ -194,11 +215,14 @@ def _ibn_mlp(bp: Params, x: jax.Array, ibn_chunks: int = 0) -> jax.Array:
 def conv_encoder_block(bp: Params, x: jax.Array,
                        ibn_chunks: int = 0) -> jax.Array:
     """dw conv kxk -> LN -> pw 4x -> GELU -> pw -> layer scale -> residual."""
-    h = depthwise_conv2d(x, bp["dw_w"].astype(x.dtype),
-                         bp["dw_b"].astype(x.dtype))
-    h = layer_norm(h, bp["ln"]["scale"], bp["ln"]["bias"])
+    with jax.named_scope("dw"):
+        h = depthwise_conv2d(x, bp["dw_w"].astype(x.dtype),
+                             bp["dw_b"].astype(x.dtype))
+    with jax.named_scope("ln"):
+        h = layer_norm(h, bp["ln"]["scale"], bp["ln"]["bias"])
     h = _ibn_mlp(bp, h, ibn_chunks)
-    return x + bp["gamma"].astype(x.dtype) * h
+    with jax.named_scope("res"):
+        return x + bp["gamma"].astype(x.dtype) * h
 
 
 def xca(bp: Params, x: jax.Array, heads: int) -> jax.Array:
@@ -209,20 +233,25 @@ def xca(bp: Params, x: jax.Array, heads: int) -> jax.Array:
     """
     B, N, C = x.shape
     dtype = x.dtype
-    qkv = x @ bp["qkv_w"].astype(dtype) + bp["qkv_b"].astype(dtype)
-    qkv = qkv.reshape(B, N, 3, heads, C // heads)
-    q, k, v = [qkv[:, :, i].transpose(0, 2, 3, 1) for i in range(3)]
+    with jax.named_scope("qkv"):
+        qkv = x @ bp["qkv_w"].astype(dtype) + bp["qkv_b"].astype(dtype)
+        qkv = qkv.reshape(B, N, 3, heads, C // heads)
+        q, k, v = [qkv[:, :, i].transpose(0, 2, 3, 1) for i in range(3)]
     # q,k,v: [B, h, C/h, N] — channels are the "tokens" of this attention
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    qf = qf / (jnp.linalg.norm(qf, axis=-1, keepdims=True) + 1e-6)
-    kf = kf / (jnp.linalg.norm(kf, axis=-1, keepdims=True) + 1e-6)
-    attn = jax.nn.softmax(
-        jnp.einsum("bhcn,bhdn->bhcd", qf, kf)
-        * bp["temp"].astype(jnp.float32), axis=-1)
-    out = jnp.einsum("bhcd,bhdn->bhcn", attn.astype(dtype), v)
-    out = out.transpose(0, 3, 1, 2).reshape(B, N, C)
-    return out @ bp["proj_w"].astype(dtype) + bp["proj_b"].astype(dtype)
+    with jax.named_scope("qk"):
+        qf = q.astype(jnp.float32)
+        kf = k.astype(jnp.float32)
+        qf = qf / (jnp.linalg.norm(qf, axis=-1, keepdims=True) + 1e-6)
+        kf = kf / (jnp.linalg.norm(kf, axis=-1, keepdims=True) + 1e-6)
+        scores = jnp.einsum("bhcn,bhdn->bhcd", qf, kf) \
+            * bp["temp"].astype(jnp.float32)
+    with jax.named_scope("sm"):
+        attn = jax.nn.softmax(scores, axis=-1)
+    with jax.named_scope("av"):
+        out = jnp.einsum("bhcd,bhdn->bhcn", attn.astype(dtype), v)
+    with jax.named_scope("proj"):
+        out = out.transpose(0, 3, 1, 2).reshape(B, N, C)
+        return out @ bp["proj_w"].astype(dtype) + bp["proj_b"].astype(dtype)
 
 
 def sdta_block(bp: Params, x: jax.Array, heads: int, scales: int,
@@ -232,29 +261,37 @@ def sdta_block(bp: Params, x: jax.Array, heads: int, scales: int,
     dtype = x.dtype
     widths = _split_widths(C, scales)
     if scales > 1:
-        splits = jnp.split(x, np_cumsum(widths)[:-1], axis=-1)
+        with jax.named_scope("dw0"):
+            splits = jnp.split(x, np_cumsum(widths)[:-1], axis=-1)
         outs = [splits[0]]
         prev = None
         for i, sp in enumerate(splits[1:]):
-            # the remainder split can be narrower than the one before
-            # it (160 = 54 + 54 + 52): it adds the leading channels
-            inp = sp if prev is None else sp + prev[..., :sp.shape[-1]]
-            prev = depthwise_conv2d(inp, bp["dw"][i]["w"].astype(dtype),
-                                    bp["dw"][i]["b"].astype(dtype))
+            with jax.named_scope(f"dw{i}"):
+                # the remainder split can be narrower than the one before
+                # it (160 = 54 + 54 + 52): it adds the leading channels
+                inp = sp if prev is None else sp + prev[..., :sp.shape[-1]]
+                prev = depthwise_conv2d(inp,
+                                        bp["dw"][i]["w"].astype(dtype),
+                                        bp["dw"][i]["b"].astype(dtype))
             outs.append(prev)
-        h = jnp.concatenate(outs, axis=-1)
+        with jax.named_scope(f"dw{len(splits) - 2}"):
+            h = jnp.concatenate(outs, axis=-1)
     else:
         h = x
     # transposed attention on flattened tokens
-    hn = h.reshape(B, H * W, C)
-    a = layer_norm(hn, bp["ln_x"]["scale"], bp["ln_x"]["bias"])
+    with jax.named_scope("ln_x"):
+        hn = h.reshape(B, H * W, C)
+        a = layer_norm(hn, bp["ln_x"]["scale"], bp["ln_x"]["bias"])
     a = xca(bp, a, heads)
-    hn = hn + bp["gamma_x"].astype(dtype) * a
+    with jax.named_scope("res"):
+        hn = hn + bp["gamma_x"].astype(dtype) * a
     # inverted-bottleneck MLP
-    m = layer_norm(hn, bp["ln_m"]["scale"], bp["ln_m"]["bias"])
+    with jax.named_scope("ln_m"):
+        m = layer_norm(hn, bp["ln_m"]["scale"], bp["ln_m"]["bias"])
     m = _ibn_mlp(bp, m, ibn_chunks)
-    hn = hn + bp["gamma_m"].astype(dtype) * m
-    return hn.reshape(B, H, W, C)
+    with jax.named_scope("res"):
+        hn = hn + bp["gamma_m"].astype(dtype) * m
+        return hn.reshape(B, H, W, C)
 
 
 def np_cumsum(widths: List[int]) -> List[int]:
@@ -273,23 +310,33 @@ def np_cumsum(widths: List[int]) -> List[int]:
 def forward(cfg: EdgeNeXtConfig, params: Params, images: jax.Array, *,
             ibn_chunks: int = 0) -> jax.Array:
     """images: [B, img, img, 3] -> logits [B, num_classes]."""
-    x = images.astype(jnp.dtype(cfg.dtype))
     for si in range(4):
         sp = params["stages"][si]
         if si == 0:
-            x = conv2d(x, sp["down_w"].astype(x.dtype),
-                       sp["down_b"].astype(x.dtype), stride=4,
-                       padding="VALID")
+            with jax.named_scope("stem"):
+                x = images.astype(jnp.dtype(cfg.dtype))
+                x = conv2d(x, sp["down_w"].astype(x.dtype),
+                           sp["down_b"].astype(x.dtype), stride=4,
+                           padding="VALID")
         else:
-            x = layer_norm(x, sp["down_ln"]["scale"], sp["down_ln"]["bias"])
-            x = conv2d(x, sp["down_w"].astype(x.dtype),
-                       sp["down_b"].astype(x.dtype), stride=2,
-                       padding="VALID")
-        for bp in sp["conv_blocks"]:
-            x = conv_encoder_block(bp, x, ibn_chunks)
-        for bp in sp["sdta_blocks"]:
-            x = sdta_block(bp, x, cfg.heads, cfg.sdta_scales[si], ibn_chunks)
-    x = x.mean(axis=(1, 2))                                   # global pool
-    x = layer_norm(x, params["head_ln"]["scale"], params["head_ln"]["bias"])
-    return (x @ params["head_w"].astype(x.dtype)
-            + params["head_b"].astype(x.dtype)).astype(jnp.float32)
+            with jax.named_scope(f"s{si}.down_ln"):
+                x = layer_norm(x, sp["down_ln"]["scale"],
+                               sp["down_ln"]["bias"])
+            with jax.named_scope(f"s{si}.down"):
+                x = conv2d(x, sp["down_w"].astype(x.dtype),
+                           sp["down_b"].astype(x.dtype), stride=2,
+                           padding="VALID")
+        for bi, bp in enumerate(sp["conv_blocks"]):
+            with jax.named_scope(f"s{si}.conv{bi}"):
+                x = conv_encoder_block(bp, x, ibn_chunks)
+        for bi, bp in enumerate(sp["sdta_blocks"]):
+            with jax.named_scope(f"s{si}.sdta{bi}"):
+                x = sdta_block(bp, x, cfg.heads, cfg.sdta_scales[si],
+                               ibn_chunks)
+    with jax.named_scope("head.ln"):
+        x = x.mean(axis=(1, 2))                               # global pool
+        x = layer_norm(x, params["head_ln"]["scale"],
+                       params["head_ln"]["bias"])
+    with jax.named_scope("head.fc"):
+        return (x @ params["head_w"].astype(x.dtype)
+                + params["head_b"].astype(x.dtype)).astype(jnp.float32)

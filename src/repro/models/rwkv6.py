@@ -12,6 +12,15 @@ This is the paper-technique transfer for the attention-free arch (DESIGN.md
 §Arch-applicability): like the inverted-bottleneck fusion, the chunked form
 keeps the outer-product intermediates in fast memory instead of streaming the
 full-state recurrence through HBM per token.
+
+Every op sits in a ``jax.named_scope`` spelled as the scheduler's layer
+(``repro.core.workload.rwkv6_workload``) without its block index, since
+one scan body serves every block: ``ln1``, ``tmix``/``rkvg``, ``wkv``,
+``gn``, ``out``, ``res1``, ``ln2``, ``cmix``/``key``, ``act``, ``value``,
+``res2``; outside the scan ``embed``, ``head.ln`` and ``head.logits``.
+A weight's cast sits in the scope of the layer that uses it, so a cast
+that XLA hoists out of the scan still counts to its layer.  Scopes are
+metadata: the compiled program is the same without them.
 """
 from __future__ import annotations
 
@@ -30,6 +39,17 @@ Params = Dict[str, Any]
 
 LORA_MIX = 32     # token-shift LoRA rank
 LORA_DECAY = 64   # decay LoRA rank
+
+# the scopes ``forward`` and ``logits_fn`` open around work the
+# scheduler's chain (``core.workload.rwkv6_workload``) leaves out
+OUTSIDE_CHAIN_SCOPES = ("embed", "head.logits")
+
+
+def chain_scope(layer: str) -> str:
+    """The scope path that spells a chain layer in ``forward``: one scan
+    body serves every block, so ``blk3.tmix.wkv`` runs as ``tmix.wkv``."""
+    block, _, rest = layer.partition(".")
+    return rest if block.startswith("blk") and block[3:].isdigit() else layer
 
 
 class RWKVCache(NamedTuple):
@@ -211,39 +231,52 @@ def time_mix(cfg: ModelConfig, tm: Params, x: jax.Array, x_prev: jax.Array,
     B, T, D = x.shape
     H = D // cfg.wkv_head_dim
     K = cfg.wkv_head_dim
-    sx = _token_shift(x, x_prev) - x
-    xw, xk, xv, xr, xg = _ddlerp(tm, x, sx)
+    with jax.named_scope("rkvg"):
+        sx = _token_shift(x, x_prev) - x
+        xw, xk, xv, xr, xg = _ddlerp(tm, x, sx)
 
-    r = (xr @ tm["wr"].astype(dtype)).reshape(B, T, H, K)
-    k = (xk @ tm["wk"].astype(dtype)).reshape(B, T, H, K)
-    v = (xv @ tm["wv"].astype(dtype)).reshape(B, T, H, K)
-    g = jax.nn.silu(xg @ tm["wg"].astype(dtype))
+        r = (xr @ tm["wr"].astype(dtype)).reshape(B, T, H, K)
+        k = (xk @ tm["wk"].astype(dtype)).reshape(B, T, H, K)
+        v = (xv @ tm["wv"].astype(dtype)).reshape(B, T, H, K)
+        g = jax.nn.silu(xg @ tm["wg"].astype(dtype))
 
-    ww = tm["decay"].astype(jnp.float32) + (
-        jnp.tanh(xw @ tm["td_w1"].astype(dtype)).astype(jnp.float32)
-        @ tm["td_w2"].astype(jnp.float32))
-    logw = -jnp.exp(ww).reshape(B, T, H, K)                   # log decay <= 0
+        ww = tm["decay"].astype(jnp.float32) + (
+            jnp.tanh(xw @ tm["td_w1"].astype(dtype)).astype(jnp.float32)
+            @ tm["td_w2"].astype(jnp.float32))
+        logw = -jnp.exp(ww).reshape(B, T, H, K)               # log decay <= 0
 
-    if T == 1:
-        out1, state = wkv_recurrent_step(
-            r[:, 0], k[:, 0], v[:, 0], logw[:, 0], tm["faaaa"], state)
-        out = out1[:, None]
-    else:
-        out, state = wkv_chunked(r, k, v, logw, tm["faaaa"], state, chunk)
-    out = out.reshape(B, T, D)
-    out = _group_norm(out, tm["lnx_scale"], tm["lnx_bias"], H)
-    out = (out * g) @ tm["wo"].astype(dtype)
+    with jax.named_scope("wkv"):
+        if T == 1:
+            out1, state = wkv_recurrent_step(
+                r[:, 0], k[:, 0], v[:, 0], logw[:, 0], tm["faaaa"], state)
+            out = out1[:, None]
+        else:
+            out, state = wkv_chunked(r, k, v, logw, tm["faaaa"], state,
+                                     chunk)
+    with jax.named_scope("gn"):
+        out = out.reshape(B, T, D)
+        out = _group_norm(out, tm["lnx_scale"], tm["lnx_bias"], H)
+    with jax.named_scope("out"):
+        out = (out * g) @ tm["wo"].astype(dtype)
     return out, x[:, -1, :], state
 
 
 def channel_mix(cm: Params, x: jax.Array, x_prev: jax.Array):
+    """Squared-ReLU channel mix.  ``key`` holds the token shift and the
+    expanding projection, ``act`` the squared ReLU, ``value`` the
+    projection back and the receptance gate."""
     dtype = x.dtype
-    sx = _token_shift(x, x_prev) - x
-    xk = x + sx * cm["maa_k"].astype(dtype)
-    xr = x + sx * cm["maa_r"].astype(dtype)
-    kk = jax.nn.relu(xk @ cm["wk"].astype(dtype))
-    kv = (kk * kk) @ cm["wv"].astype(dtype)
-    return jax.nn.sigmoid(xr @ cm["wr"].astype(dtype)) * kv, x[:, -1, :]
+    with jax.named_scope("key"):
+        sx = _token_shift(x, x_prev) - x
+        xk = x + sx * cm["maa_k"].astype(dtype)
+        xr = x + sx * cm["maa_r"].astype(dtype)
+        kk = xk @ cm["wk"].astype(dtype)
+    with jax.named_scope("act"):
+        kk = jax.nn.relu(kk)
+        kk = kk * kk
+    with jax.named_scope("value"):
+        kv = kk @ cm["wv"].astype(dtype)
+        return jax.nn.sigmoid(xr @ cm["wr"].astype(dtype)) * kv, x[:, -1, :]
 
 
 # ---------------------------------------------------------------------------
@@ -254,9 +287,11 @@ def channel_mix(cm: Params, x: jax.Array, x_prev: jax.Array):
 def forward(cfg: ModelConfig, params: Params, batch: Dict[str, Any], *,
             remat: bool = True, scan_unroll: int = 1,
             **_) -> Tuple[jax.Array, jax.Array]:
-    x = L.embed_tokens(params["embed"], batch["tokens"], cfg.compute_dtype)
-    x = actshard.batch_sharded(x)
-    x = L.norm_apply(cfg, params["ln0"], x)
+    with jax.named_scope("embed"):
+        x = L.embed_tokens(params["embed"], batch["tokens"],
+                           cfg.compute_dtype)
+        x = actshard.batch_sharded(x)
+        x = L.norm_apply(cfg, params["ln0"], x)
     B, T, D = x.shape
     H = D // cfg.wkv_head_dim
     zeros_prev = jnp.zeros((B, D), cfg.compute_dtype)
@@ -265,23 +300,31 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, Any], *,
 
     def body(x, bp):
         x = actshard.batch_sharded(x)
-        h = L.norm_apply(cfg, bp["ln1"], x)
-        h, _, _ = time_mix(cfg, bp["tm"], h, zeros_prev, zeros_state,
-                           cfg.wkv_chunk)
-        x = x + h
-        h = L.norm_apply(cfg, bp["ln2"], x)
-        h, _ = channel_mix(bp["cm"], h, zeros_prev)
-        return x + h, None
+        with jax.named_scope("ln1"):
+            h = L.norm_apply(cfg, bp["ln1"], x)
+        with jax.named_scope("tmix"):
+            h, _, _ = time_mix(cfg, bp["tm"], h, zeros_prev, zeros_state,
+                               cfg.wkv_chunk)
+        with jax.named_scope("res1"):
+            x = x + h
+        with jax.named_scope("ln2"):
+            h = L.norm_apply(cfg, bp["ln2"], x)
+        with jax.named_scope("cmix"):
+            h, _ = channel_mix(bp["cm"], h, zeros_prev)
+        with jax.named_scope("res2"):
+            return x + h, None
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
     x, _ = lax.scan(body, x, params["blocks"], unroll=scan_unroll)
-    x = L.norm_apply(cfg, params["ln_f"], x)
+    with jax.named_scope("head.ln"):
+        x = L.norm_apply(cfg, params["ln_f"], x)
     return x, jnp.zeros((), jnp.float32)
 
 
 def logits_fn(cfg: ModelConfig, params: Params, hidden: jax.Array):
-    return actshard.logits_sharded(L.lm_logits(params["embed"], hidden))
+    with jax.named_scope("head.logits"):
+        return actshard.logits_sharded(L.lm_logits(params["embed"], hidden))
 
 
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int) -> RWKVCache:

@@ -1,6 +1,6 @@
 """repro.obs — observability for the scheduler stack.
 
-Three pieces:
+Four pieces:
 
   ``tracer``    — hierarchical span ``Tracer`` (nested wall-time spans
                   with attributes, thread/process-safe), typed
@@ -23,6 +23,15 @@ Three pieces:
   ``explain``   — the markdown "schedule explain" report behind the
                   CLI's ``--explain`` (per-layer mapping decisions,
                   per-level traffic/energy breakdown, fusion groups).
+  ``layers``    — the op -> layer table of a compiled program
+                  (``op_layers``): each HLO instruction's scheduler layer,
+                  read from the ``jax.named_scope`` the model forwards
+                  open per layer, and each scope's scheduler class
+                  (``layer_classes``, over a chain and the spelling its
+                  model module declares).  A tracer made with
+                  ``Tracer(profiler=True)`` also writes its spans onto a
+                  running ``jax.profiler`` trace (``serve.request``), on
+                  the clock of the device's ops.
 
 Typical capture::
 
@@ -36,10 +45,11 @@ from repro.obs.tracer import (Span, Tracer, activate, count, current,
                               event, gauge, span, tracing)
 from repro.obs.exporters import bench_rows, chrome_trace, write_chrome_trace
 from repro.obs.explain import explain_schedule
+from repro.obs.layers import layer_classes, op_layers
 
 __all__ = [
     "Span", "Tracer", "activate", "count", "current", "event", "gauge",
     "span", "tracing",
     "bench_rows", "chrome_trace", "write_chrome_trace",
-    "explain_schedule",
+    "explain_schedule", "layer_classes", "op_layers",
 ]

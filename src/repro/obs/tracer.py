@@ -16,6 +16,13 @@ early return) when none is active, so an uninstrumented run pays one
 global load + ``None`` check per hook and the searched schedules stay
 bit-identical (pinned against the goldens in ``tests/test_obs.py``).
 
+A tracer made with ``profiler=True`` also enters a
+``jax.profiler.TraceAnnotation`` of the span's name for each span it
+opens, so that under a running ``jax.profiler`` trace the program's spans
+land on the trace's host plane, on the clock its device ops are read on.
+The annotation is entered and left with the span; with no tracer active
+nothing reaches the profiler.
+
 Thread safety: each thread keeps its own open-span stack
 (``threading.local``), so spans opened on different threads nest
 independently; finished root spans append to the shared tree under a
@@ -77,20 +84,28 @@ class Span:
 class _SpanCtx:
     """Context half of ``Tracer.span``: pushes the (already attached)
     span on the calling thread's stack, pops and stamps the duration on
-    exit."""
+    exit; with the tracer's profiler flag, inside a profiler annotation
+    of the span's name."""
 
-    __slots__ = ("_t", "_sp")
+    __slots__ = ("_t", "_sp", "_ann")
 
     def __init__(self, tracer: "Tracer", sp: Span) -> None:
         self._t = tracer
         self._sp = sp
+        self._ann = None
 
     def __enter__(self) -> Span:
         self._t._stack().append(self._sp)
+        annotation = self._t._annotation
+        if annotation is not None:
+            self._ann = annotation(self._sp.name)
+            self._ann.__enter__()
         return self._sp
 
     def __exit__(self, *exc) -> None:
         t, sp = self._t, self._sp
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         t._stack().pop()
         sp.dur_s = (time.perf_counter() - t.epoch) - sp.t0
 
@@ -98,9 +113,14 @@ class _SpanCtx:
 class Tracer:
     """Span tree + counters/gauges + the legacy ``phase_s`` table for
     one traced run.  See the module docstring for the threading /
-    process model."""
+    process model.  ``profiler=True`` puts each span on a running
+    ``jax.profiler`` trace as well (module docstring)."""
 
-    def __init__(self) -> None:
+    def __init__(self, *, profiler: bool = False) -> None:
+        self._annotation = None
+        if profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.epoch = time.perf_counter()
         self.phase_s: Dict[str, float] = {}
         self.counters: Dict[str, int] = {}

@@ -33,6 +33,7 @@ __all__ = [
     "load_schedule", "save_schedule", "schedule_key", "DsePoint",
     "edp_best", "hw_variants", "memory_variants", "pareto_front", "sweep",
     "sweep_memory", "WORKLOADS", "get_workload", "parse_workload",
+    "layer_scopes",
 ]
 
 
@@ -83,6 +84,31 @@ def parse_workload(name: str) -> tuple:
     if m and int(m.group(2)) >= 1:
         return m.group(1), int(m.group(2))
     return name, 1
+
+
+# the model module whose forward runs a base workload's chain in layer
+# scopes; it may spell them apart from the chain (``chain_scope``) and
+# open more (``OUTSIDE_CHAIN_SCOPES``)
+FORWARDS = {"edgenext-s": "repro.models.edgenext",
+            "edgenext-reduced": "repro.models.edgenext",
+            "rwkv6": "repro.models.rwkv6"}
+
+
+def layer_scopes(name: str) -> dict:
+    """``{scope path: (op, ibn_role)}`` of the layer scopes that the
+    forward behind a registered workload opens
+    (``repro.obs.layers.layer_classes``), the names
+    ``repro.obs.op_layers`` reads a compiled program's layers by."""
+    import importlib
+    from repro.obs.layers import layer_classes
+    base, _ = parse_workload(name)
+    if base not in FORWARDS:
+        raise KeyError(f"no forward with layer scopes runs {name!r}; "
+                       f"choose from {sorted(FORWARDS)}")
+    module = importlib.import_module(FORWARDS[base])
+    return layer_classes(get_workload(name),
+                         getattr(module, "chain_scope", None),
+                         getattr(module, "OUTSIDE_CHAIN_SCOPES", ()))
 
 
 WORKLOADS = ("edgenext-s", "edgenext-s-b4", "edgenext-reduced", "vit-tiny",

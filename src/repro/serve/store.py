@@ -53,7 +53,10 @@ artifact into memory.  A worker that dies (``serve.warm.worker_failed``)
 only costs its head start: the parent's serial faulting pass re-runs
 that grid point through the full serving ladder.  Every outcome is
 visible through the ``cache.*`` obs counters (+ ``serve.store.mem_hit``
-for memory-layer hits).
+for memory-layer hits).  Each ``request`` is one ``serve.request`` span,
+whose ``outcome`` attribute names the rung that answered; under a tracer
+made with ``profiler=True`` the span also lands on a running
+``jax.profiler`` trace.
 """
 from __future__ import annotations
 
@@ -403,7 +406,17 @@ class ServeStore:
         degradation ladder (see the module docstring).  Always returns
         a ``LookupResult`` whose ``schedule`` is servable — never None,
         never an unbounded stall (``deadline_s`` caps the cold-search
-        envelope; default is the store's ``search_deadline_s``)."""
+        envelope; default is the store's ``search_deadline_s``).  One
+        ``serve.request`` span, whose ``outcome`` is the result's."""
+        with obs.span("serve.request", workload=workload,
+                      batch=batch) as sp:
+            res = self._request(workload, batch, deadline_s)
+            if sp is not None:
+                sp.attrs["outcome"] = res.outcome
+            return res
+
+    def _request(self, workload: str, batch: int,
+                 deadline_s) -> LookupResult:
         name, layers, key = self.resolve(workload, batch)
         base, b_abs = parse_workload(name)
         # rung 1: memory
@@ -411,8 +424,6 @@ class ServeStore:
         if sched is not None:
             obs.count("cache.hit")
             obs.count("serve.store.mem_hit")
-            obs.event("serve.lookup", workload=name, key=key,
-                      outcome="mem_hit")
             return LookupResult(sched, name, key, b_abs, "mem", False)
         # rung 2: disk replay (artifact parse + remap, no DP)
         sched, _why = try_replay(self.cache_dir / f"{name}-{key}.json",
@@ -421,8 +432,6 @@ class ServeStore:
         if sched is not None:
             if self._replay_ok(layers, sched, name):
                 self._mem[key] = sched
-                obs.event("serve.lookup", workload=name, key=key,
-                          outcome="disk_hit")
                 return LookupResult(sched, name, key, b_abs, "disk",
                                     False)
             bad_replay = True
@@ -436,8 +445,6 @@ class ServeStore:
                                                       budget,
                                                       refresh=bad_replay)
             self._mem[key] = sched
-            obs.event("serve.lookup", workload=name, key=key,
-                      outcome="searched", attempts=attempts)
             return LookupResult(sched, name, key, b_abs, "searched",
                                 False, attempts)
         except Exception as e:             # noqa: BLE001 — degrade, never
@@ -451,9 +458,6 @@ class ServeStore:
             neighbor, cb = alt
             out = self._rescale(neighbor, name, key, b_abs / cb)
             obs.count("serve.degrade.nearest_batch")
-            obs.event("serve.lookup", workload=name, key=key,
-                      outcome="nearest_batch", from_batch=cb,
-                      to_batch=b_abs)
             return LookupResult(out, name, key, b_abs, "nearest_batch",
                                 True, attempts, err)
         # rung 5: the untiled heuristic — cannot fail
@@ -464,8 +468,6 @@ class ServeStore:
                                        spatial_mode=self.spatial_mode)
             self._fallback[key] = sched
         obs.count("serve.degrade.heuristic")
-        obs.event("serve.lookup", workload=name, key=key,
-                  outcome="heuristic")
         return LookupResult(sched, name, key, b_abs, "heuristic", True,
                             attempts, err)
 
