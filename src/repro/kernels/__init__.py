@@ -10,6 +10,9 @@
                   as an unrolled temporal accumulation (no MXU)
   rwkv_chunk      beyond-paper: chunked WKV6 recurrence, state + decay
                   tensors VMEM-resident
+  stacked_proj    x @ w[layer] read from a whole [L, K, N] weight stack,
+                  each tile cast to the compute dtype in VMEM (RWKV-6's
+                  projections)
 
 ``ops`` exposes jit'd wrappers (auto-padding, interpret=True off-TPU);
 ``ref`` holds the pure-jnp oracles every kernel is tested against.

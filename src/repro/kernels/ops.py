@@ -14,14 +14,16 @@ sizes unchanged on EdgeNeXt's odd channel/pixel extents.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels import (depthwise_conv as _dw, flash_attention as _fa,
                            fused_ibn as _ibn, matmul_ln as _mln,
-                           rwkv_chunk as _wkv)
+                           rwkv_chunk as _wkv, stacked_proj as _sp)
 
 
 _LANE = 128      # TPU lane width: the last block dim's granularity
@@ -62,6 +64,70 @@ def fused_ibn(x: jax.Array, w1: jax.Array, w2: jax.Array,
                          block_m=bm, block_f=bf, interpret=interp,
                          valid_f=F)
     return out[:M].reshape(*lead, w2.shape[1])
+
+
+# stacked_proj's blocks, chosen on a v5e at M = 512 (PERF.md section 6):
+# a 512 x 2048 f32 weight tile, double-buffered, fits the default scoped
+# VMEM; 1024 x 2048 does not
+_PROJ_BM, _PROJ_BK, _PROJ_BN = 512, 512, 2048
+
+
+def _lane_block(n: int, block: int) -> int:
+    """A weight block for extent ``n``: the whole extent if it fits in
+    ``block``, else the largest multiple of 128 up to ``block`` that
+    divides ``n``.  A weight stack is read in place and never padded:
+    a pad would copy the whole stack on every call."""
+    if n <= block:
+        return n
+    for b in range(block // _LANE * _LANE, 0, -_LANE):
+        if n % b == 0:
+            return b
+    raise ValueError(f"no multiple of {_LANE} up to {block} divides {n}")
+
+
+def stacked_proj(x: jax.Array, w: jax.Array, layer: jax.Array, *,
+                 interpret: Optional[bool] = None) -> jax.Array:
+    """x [..., K] @ w[layer] for a weight stack w [L, K, N] in its stored
+    dtype, in x's dtype: each weight tile is cast to x's dtype in VMEM,
+    with f32 accumulation.  Differentiable in x and w.  Unlike the other
+    wrappers it pads only x: its weight blocks divide K and N or are
+    whole (``_lane_block``)."""
+    interp = (not _on_tpu()) if interpret is None else interpret
+    lead = x.shape[:-1]
+    out = _stacked_proj(x.reshape(-1, x.shape[-1]), w,
+                        jnp.asarray(layer, jnp.int32), interp)
+    return out.reshape(*lead, w.shape[2])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _stacked_proj(x, w, layer, interp):
+    (M, K), N = x.shape, w.shape[2]
+    bm = min(_PROJ_BM, M)
+    out = _sp.stacked_proj(_pad_to(x, 0, bm), w, layer, block_m=bm,
+                           block_k=_lane_block(K, _PROJ_BK),
+                           block_n=_lane_block(N, _PROJ_BN),
+                           interpret=interp)
+    return out[:M]
+
+
+def _stacked_proj_fwd(x, w, layer, interp):
+    return _stacked_proj(x, w, layer, interp), (x, w, layer)
+
+
+def _stacked_proj_bwd(interp, res, g):
+    """dx through the cast layer; dw is that layer's gradient in a zeroed
+    stack, which XLA folds into the layer scan's gradient accumulation as
+    an in-place update of the one layer."""
+    x, w, layer = res
+    wl = lax.dynamic_index_in_dim(w, layer, keepdims=False)
+    dx = jnp.dot(g, wl.astype(x.dtype).T, preferred_element_type=jnp.float32)
+    dwl = jnp.dot(x.T, g, preferred_element_type=jnp.float32)
+    dw = lax.dynamic_update_index_in_dim(jnp.zeros_like(w),
+                                         dwl.astype(w.dtype), layer, 0)
+    return dx.astype(x.dtype), dw, None
+
+
+_stacked_proj.defvjp(_stacked_proj_fwd, _stacked_proj_bwd)
 
 
 def matmul_ln(x: jax.Array, w: jax.Array, b: jax.Array, gamma: jax.Array,

@@ -18,9 +18,20 @@ Every op sits in a ``jax.named_scope`` spelled as the scheduler's layer
 one scan body serves every block: ``ln1``, ``tmix``/``rkvg``, ``wkv``,
 ``gn``, ``out``, ``res1``, ``ln2``, ``cmix``/``key``, ``act``, ``value``,
 ``res2``; outside the scan ``embed``, ``head.ln`` and ``head.logits``.
-A weight's cast sits in the scope of the layer that uses it, so a cast
-that XLA hoists out of the scan still counts to its layer.  Scopes are
-metadata: the compiled program is the same without them.
+A small leaf's cast sits in the scope of the layer that uses it, so a
+cast that XLA hoists out of the scan still counts to its layer.  Scopes
+are metadata: the compiled program is the same without them.
+
+On one device the layer scan runs over the layer index.  The eight
+projection weights (``STACKED``) stay whole ``[L, K, N]`` stacks in their
+stored dtype and ``kernels.ops.stacked_proj`` reads layer ``i``'s tiles
+from them, casting each in VMEM.  Sliced in the scan's ``xs`` and cast by
+XLA, they would be cast whole, every layer at once, on every call: XLA
+hoists the cast of a loop-invariant stack out of the loop.  The small
+per-layer leaves (mixes, LoRAs, decay, norms) are scanned.  Under an
+``actshard`` mesh every leaf is scanned and XLA casts each projection's
+slice: the SPMD partitioner cannot split a Pallas call, and would gather
+the stacks and the whole batch onto every chip to run it.
 """
 from __future__ import annotations
 
@@ -43,6 +54,9 @@ LORA_DECAY = 64   # decay LoRA rank
 # the scopes ``forward`` and ``logits_fn`` open around work the
 # scheduler's chain (``core.workload.rwkv6_workload``) leaves out
 OUTSIDE_CHAIN_SCOPES = ("embed", "head.logits")
+
+# the projection weights the layer scan leaves whole: ``blocks[g][n]``
+STACKED = {"tm": ("wr", "wk", "wv", "wg", "wo"), "cm": ("wk", "wv", "wr")}
 
 
 def chain_scope(layer: str) -> str:
@@ -224,9 +238,21 @@ def _group_norm(x: jax.Array, scale, bias, heads: int) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def time_mix(cfg: ModelConfig, tm: Params, x: jax.Array, x_prev: jax.Array,
-             state, chunk: int):
-    """Returns (out [B,T,D], new_x_prev [B,D], new_state)."""
+def _proj(h: jax.Array, w: jax.Array, layer) -> jax.Array:
+    """``h @ w[layer]`` in h's dtype for a whole ``STACKED`` stack ``w``;
+    ``h @ w`` for the layer's own slice where ``layer`` is None."""
+    if layer is None:
+        return h @ w.astype(h.dtype)
+    # imported where a projection is traced, not with the model registry:
+    # a process that runs another model never loads Pallas
+    from repro.kernels import ops
+    return ops.stacked_proj(h, w, layer)
+
+
+def time_mix(cfg: ModelConfig, tm: Params, layer, x: jax.Array,
+             x_prev: jax.Array, state, chunk: int):
+    """Returns (out [B,T,D], new_x_prev [B,D], new_state).  ``tm`` and
+    ``layer`` as ``_layer_scan`` hands them to its body."""
     dtype = x.dtype
     B, T, D = x.shape
     H = D // cfg.wkv_head_dim
@@ -235,10 +261,10 @@ def time_mix(cfg: ModelConfig, tm: Params, x: jax.Array, x_prev: jax.Array,
         sx = _token_shift(x, x_prev) - x
         xw, xk, xv, xr, xg = _ddlerp(tm, x, sx)
 
-        r = (xr @ tm["wr"].astype(dtype)).reshape(B, T, H, K)
-        k = (xk @ tm["wk"].astype(dtype)).reshape(B, T, H, K)
-        v = (xv @ tm["wv"].astype(dtype)).reshape(B, T, H, K)
-        g = jax.nn.silu(xg @ tm["wg"].astype(dtype))
+        r = _proj(xr, tm["wr"], layer).reshape(B, T, H, K)
+        k = _proj(xk, tm["wk"], layer).reshape(B, T, H, K)
+        v = _proj(xv, tm["wv"], layer).reshape(B, T, H, K)
+        g = jax.nn.silu(_proj(xg, tm["wg"], layer))
 
         ww = tm["decay"].astype(jnp.float32) + (
             jnp.tanh(xw @ tm["td_w1"].astype(dtype)).astype(jnp.float32)
@@ -257,31 +283,61 @@ def time_mix(cfg: ModelConfig, tm: Params, x: jax.Array, x_prev: jax.Array,
         out = out.reshape(B, T, D)
         out = _group_norm(out, tm["lnx_scale"], tm["lnx_bias"], H)
     with jax.named_scope("out"):
-        out = (out * g) @ tm["wo"].astype(dtype)
+        out = _proj(out * g, tm["wo"], layer)
     return out, x[:, -1, :], state
 
 
-def channel_mix(cm: Params, x: jax.Array, x_prev: jax.Array):
+def channel_mix(cm: Params, layer, x: jax.Array, x_prev: jax.Array):
     """Squared-ReLU channel mix.  ``key`` holds the token shift and the
     expanding projection, ``act`` the squared ReLU, ``value`` the
-    projection back and the receptance gate."""
+    projection back and the receptance gate.  ``cm`` as ``time_mix``'s
+    ``tm``."""
     dtype = x.dtype
     with jax.named_scope("key"):
         sx = _token_shift(x, x_prev) - x
         xk = x + sx * cm["maa_k"].astype(dtype)
         xr = x + sx * cm["maa_r"].astype(dtype)
-        kk = xk @ cm["wk"].astype(dtype)
+        kk = _proj(xk, cm["wk"], layer)
     with jax.named_scope("act"):
         kk = jax.nn.relu(kk)
         kk = kk * kk
     with jax.named_scope("value"):
-        kv = kk @ cm["wv"].astype(dtype)
-        return jax.nn.sigmoid(xr @ cm["wr"].astype(dtype)) * kv, x[:, -1, :]
+        kv = _proj(kk, cm["wv"], layer)
+        rr = _proj(xr, cm["wr"], layer)
+        return jax.nn.sigmoid(rr) * kv, x[:, -1, :]
 
 
 # ---------------------------------------------------------------------------
 # Model entry points
 # ---------------------------------------------------------------------------
+
+
+def _layer_scan(cfg: ModelConfig, params: Params, body, x, xs=(),
+                unroll: int = 1):
+    """``lax.scan`` of ``body(x, layer, bp, *xs_i)`` over the layers.  On
+    one device ``layer`` is the layer index and ``bp`` holds the layer's
+    slice of the small leaves and the whole ``STACKED`` stacks; under an
+    ``actshard`` mesh ``layer`` is None and ``bp`` the layer's slice of
+    every leaf (module docstring)."""
+    blocks = params["blocks"]
+    if actshard.current_mesh() is not None:
+        def sliced(x, scanned):
+            bp, *rest = scanned
+            return body(x, None, bp, *rest)
+        return lax.scan(sliced, x, (blocks, *xs), unroll=unroll)
+
+    stacks = {g: {n: blocks[g][n] for n in names}
+              for g, names in STACKED.items()}
+    small = {g: {n: v for n, v in p.items() if n not in STACKED.get(g, ())}
+             for g, p in blocks.items()}
+
+    def step(x, scanned):
+        layer, bp, *rest = scanned
+        bp = {g: {**p, **stacks.get(g, {})} for g, p in bp.items()}
+        return body(x, layer, bp, *rest)
+
+    layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    return lax.scan(step, x, (layers, small, *xs), unroll=unroll)
 
 
 def forward(cfg: ModelConfig, params: Params, batch: Dict[str, Any], *,
@@ -298,25 +354,25 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, Any], *,
     zeros_state = jnp.zeros((B, H, cfg.wkv_head_dim, cfg.wkv_head_dim),
                             jnp.float32)
 
-    def body(x, bp):
+    def body(x, layer, bp):
         x = actshard.batch_sharded(x)
         with jax.named_scope("ln1"):
             h = L.norm_apply(cfg, bp["ln1"], x)
         with jax.named_scope("tmix"):
-            h, _, _ = time_mix(cfg, bp["tm"], h, zeros_prev, zeros_state,
-                               cfg.wkv_chunk)
+            h, _, _ = time_mix(cfg, bp["tm"], layer, h, zeros_prev,
+                               zeros_state, cfg.wkv_chunk)
         with jax.named_scope("res1"):
             x = x + h
         with jax.named_scope("ln2"):
             h = L.norm_apply(cfg, bp["ln2"], x)
         with jax.named_scope("cmix"):
-            h, _ = channel_mix(bp["cm"], h, zeros_prev)
+            h, _ = channel_mix(bp["cm"], layer, h, zeros_prev)
         with jax.named_scope("res2"):
             return x + h, None
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
-    x, _ = lax.scan(body, x, params["blocks"], unroll=scan_unroll)
+    x, _ = _layer_scan(cfg, params, body, x, unroll=scan_unroll)
     with jax.named_scope("head.ln"):
         x = L.norm_apply(cfg, params["ln_f"], x)
     return x, jnp.zeros((), jnp.float32)
@@ -352,18 +408,18 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, Any],
     zeros_state = jnp.zeros((B, H, cfg.wkv_head_dim, cfg.wkv_head_dim),
                             jnp.float32)
 
-    def body(x, bp):
+    def body(x, layer, bp):
         x = actshard.batch_sharded(x)
         h = L.norm_apply(cfg, bp["ln1"], x)
-        h, sh_tm, st = time_mix(cfg, bp["tm"], h, zeros_prev, zeros_state,
-                                cfg.wkv_chunk)
+        h, sh_tm, st = time_mix(cfg, bp["tm"], layer, h, zeros_prev,
+                                zeros_state, cfg.wkv_chunk)
         x = x + h
         h = L.norm_apply(cfg, bp["ln2"], x)
-        h, sh_cm = channel_mix(bp["cm"], h, zeros_prev)
+        h, sh_cm = channel_mix(bp["cm"], layer, h, zeros_prev)
         return x + h, (st, sh_tm, sh_cm)
 
-    x, (st, sh_tm, sh_cm) = lax.scan(body, x, params["blocks"],
-                                     unroll=scan_unroll)
+    x, (st, sh_tm, sh_cm) = _layer_scan(cfg, params, body, x,
+                                        unroll=scan_unroll)
     x = L.norm_apply(cfg, params["ln_f"], x)
     cache = RWKVCache(state=st, shift_tm=sh_tm, shift_cm=sh_cm,
                       step=jnp.array(T, jnp.int32))
@@ -376,18 +432,18 @@ def decode_step(cfg: ModelConfig, params: Params, cache: RWKVCache,
     x = L.embed_tokens(params["embed"], batch["tokens"], cfg.compute_dtype)
     x = L.norm_apply(cfg, params["ln0"], x)
 
-    def body(x, scanned):
-        bp, st, sh_tm, sh_cm = scanned
+    def body(x, layer, bp, st, sh_tm, sh_cm):
         h = L.norm_apply(cfg, bp["ln1"], x)
-        h, sh_tm, st = time_mix(cfg, bp["tm"], h, sh_tm, st, cfg.wkv_chunk)
+        h, sh_tm, st = time_mix(cfg, bp["tm"], layer, h, sh_tm, st,
+                                cfg.wkv_chunk)
         x = x + h
         h = L.norm_apply(cfg, bp["ln2"], x)
-        h, sh_cm = channel_mix(bp["cm"], h, sh_cm)
+        h, sh_cm = channel_mix(bp["cm"], layer, h, sh_cm)
         return x + h, (st, sh_tm, sh_cm)
 
-    x, (st, sh_tm, sh_cm) = lax.scan(
-        body, x, (params["blocks"], cache.state, cache.shift_tm,
-                  cache.shift_cm), unroll=scan_unroll)
+    x, (st, sh_tm, sh_cm) = _layer_scan(
+        cfg, params, body, x,
+        (cache.state, cache.shift_tm, cache.shift_cm), unroll=scan_unroll)
     x = L.norm_apply(cfg, params["ln_f"], x)
     logits = L.lm_logits(params["embed"], x)[:, 0, :]
     return logits, RWKVCache(state=st, shift_tm=sh_tm, shift_cm=sh_cm,

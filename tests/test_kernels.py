@@ -333,3 +333,62 @@ def test_wkv_chunk_invariance():
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(st8), np.asarray(st32),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# projection by one layer of a stacked weight, cast in VMEM
+# ---------------------------------------------------------------------------
+
+
+def _stacked_proj_ref(x, w, layer):
+    return jnp.dot(x, w[layer].astype(x.dtype),
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+@pytest.mark.parametrize("m,k,n", [
+    (1, 64, 128),                   # decode: one row, whole extents
+    (8, 128, 256),
+    (64, 200, 300),                 # ragged K and N: whole extents
+    (8, 1536, 2304),                # K in 3 blocks of 512, N in 2 of 1152
+    (600, 128, 128),                # rows past one block: x padded
+], ids=["m1", "m8", "m64-ragged", "m8-tiled", "m600"])
+@pytest.mark.parametrize("wdtype", [jnp.float32, jnp.bfloat16])
+def test_stacked_proj_equals_the_cast_layer(m, k, n, wdtype):
+    """ops.stacked_proj(x, w, l) == x @ w[l].astype(bf16), f32
+    accumulation, for every layer l of the stack."""
+    ks = jax.random.split(KEY, 2)
+    x = jax.random.normal(ks[0], (m, k), jnp.float32).astype(jnp.bfloat16)
+    w = (jax.random.normal(ks[1], (3, k, n), jnp.float32) * 0.1
+         ).astype(wdtype)
+    for layer in range(w.shape[0]):
+        out = ops.stacked_proj(x, w, jnp.int32(layer))
+        assert out.shape == (m, n) and out.dtype == x.dtype
+        _assert_close(out, _stacked_proj_ref(x, w, layer), jnp.bfloat16)
+
+
+def test_stacked_proj_refuses_a_stack_it_would_have_to_pad():
+    """N = 2100 is over the 2048 block and no multiple of 128 divides
+    it: padding would copy the whole stack on every call."""
+    x = jnp.zeros((8, 128), jnp.bfloat16)
+    w = jnp.zeros((2, 128, 2100), jnp.float32)
+    with pytest.raises(ValueError, match="divides 2100"):
+        ops.stacked_proj(x, w, 0)
+
+
+def test_stacked_proj_gradient_is_the_plain_projections():
+    """The VJP: dx through the cast layer, dw into that layer alone."""
+    ks = jax.random.split(KEY, 3)
+    x = jax.random.normal(ks[0], (2, 8, 128), jnp.float32)
+    w = jax.random.normal(ks[1], (3, 128, 256), jnp.float32) * 0.1
+    g = jax.random.normal(ks[2], (2, 8, 256), jnp.float32)
+
+    def loss(proj):
+        return lambda x, w: jnp.sum(proj(x, w) * g)
+
+    got = jax.grad(loss(lambda x, w: ops.stacked_proj(x, w, 1)),
+                   (0, 1))(x, w)
+    want = jax.grad(loss(lambda x, w: x @ w[1]), (0, 1))(x, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=3e-5, atol=3e-5)
+    assert not np.asarray(got[1])[[0, 2]].any()

@@ -45,6 +45,36 @@ def test_wkv_chunked_equals_recurrent():
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("on_mesh", [False, True], ids=["one-device",
+                                                      "mesh"])
+def test_rwkv6_projections_are_kernels_off_a_mesh_only(on_mesh):
+    """Off a mesh each layer's eight projections are ``stacked_proj``
+    Pallas calls, in the train step's forward and in decode; under an
+    ``actshard`` mesh none is, since the partitioner cannot split one."""
+    from repro.models import actshard
+    from repro.optim import adamw_init, warmup_cosine
+    from repro.runtime import build_train_step
+    cfg = reduced(get_config("rwkv6-1.6b"))
+    params = P.init_params(KEY, rwkv6.param_defs(cfg))
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    step = build_train_step(cfg, lr_schedule=warmup_cosine(1e-3, 2, 10))
+    cache = rwkv6.init_cache(cfg, 2, 16)
+    actshard.set_mesh(jax.make_mesh(
+        (1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2) if on_mesh else None)
+    try:
+        train = jax.make_jaxpr(step)(params, adamw_init(params),
+                                     {"tokens": tokens, "labels": tokens})
+        decode = jax.make_jaxpr(
+            lambda p, c: rwkv6.decode_step(cfg, p, c,
+                                           {"tokens": tokens[:, :1]}))(
+            params, cache)
+    finally:
+        actshard.set_mesh(None)
+    for jaxpr in (train, decode):
+        assert ("pallas_call" in str(jaxpr)) is not on_mesh
+
+
 def test_rg_lru_scan_equals_stepwise():
     cfg = reduced(get_config("recurrentgemma-2b"))
     rec = P.init_params(KEY, recurrentgemma._recurrent_defs(cfg))

@@ -133,13 +133,20 @@ def test_rwkv6_spells_a_chain_layer_without_its_block(layer, scope):
     ("jit(f)/s1.conv0/while/body/closed_call/pw1/dot_general",
      "s1.conv0.pw1"),
     ("jit(f)/transpose(jvp(g))/s1.conv0/remat/pw1/add", "s1.conv0.pw1"),
+    # a Pallas kernel in its layer scope: compiled for a TPU, and run by
+    # the interpreter elsewhere
+    ("jit(f)/while/body/closed_call/checkpoint/tmix/rkvg/"
+     "jit(stacked_proj)/pallas_call", "tmix.rkvg"),
+    ("jit(f)/while/body/closed_call/checkpoint/cmix/value/"
+     "jit(stacked_proj)/while/body/dot_general", "cmix.value"),
     ("jit(f)/s1.conv0/reshape", None),          # block scope, no layer
     ("jit(f)/s1.conv0/pw9/dot_general", None),  # renamed: missing
     ("jit(f)/pw1", None),                       # a primitive, not a scope
     ("reduce_sum", None),
 ])
 def test_layer_of_reads_the_innermost_known_scope(op_name, want):
-    names = {"s1.conv0.pw1", "s1.conv0.ln", "pw1", "tmix.wkv", "ln1"}
+    names = {"s1.conv0.pw1", "s1.conv0.ln", "pw1", "tmix.wkv", "ln1",
+             "tmix.rkvg", "cmix.value"}
     assert layer_of(op_name, names) == want
 
 

@@ -13,6 +13,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -131,3 +132,114 @@ def test_kernel_compiles_for_v5e(case, one_chip, served):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the weight stacks of the served RWKV-6 and their per-layer slices
+_STACK = re.compile(r"= bf16\[(?:24,|1,)?(?:2048,2048|2048,7168|7168,2048)\]"
+                    r"\S* convert\(")
+_SLICE = re.compile(r"= \w+\[(?:1,)?(?:2048,2048|2048,7168|7168,2048)\]"
+                    r"\S* dynamic-slice\(")
+
+
+@pytest.fixture
+def kernels_for_tpu(monkeypatch):
+    """The forward's kernels lowered for the described chip: here the
+    backend is the CPU, where ``ops`` would take the interpreter."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+
+def test_rwkv6_forward_streams_its_weight_stacks(one_chip, kernels_for_tpu):
+    """The served RWKV-6 forward at full width (f32 weights, bf16
+    compute, 512 tokens): every projection is a kernel that reads its
+    layer from the whole f32 stack, so no cast or slice of a weight stack
+    is left to XLA."""
+    from repro.configs.rwkv6_1_6b import CONFIG
+    from repro.models import rwkv6
+    from repro.models.params import ParamDef
+
+    params = jax.tree.map(
+        lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32,
+                                       sharding=one_chip),
+        rwkv6.param_defs(CONFIG),
+        is_leaf=lambda d: isinstance(d, ParamDef))
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+
+    def served(p, t):
+        hidden, _ = rwkv6.forward(CONFIG, p, {"tokens": t})
+        return rwkv6.logits_fn(CONFIG, p, hidden[:, -1:, :])
+
+    hlo = jax.jit(served).lower(params, tokens).compile().as_text()
+    assert not _STACK.findall(hlo)
+    assert not _SLICE.findall(hlo)
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 8
+    assert all("/while/body/" in line for line in kernels)
+
+
+def test_stacked_proj_compiles_for_v5e_at_decodes_one_row(one_chip):
+    """cmix.value's projection, the widest K, from a [24, 7168, 2048] f32
+    stack at decode's M = 1 (the forward above runs M = 512)."""
+    args = [jax.ShapeDtypeStruct((1, 7168), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((24, 7168, 2048), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)]
+    fn = functools.partial(ops.stacked_proj, interpret=False)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_rwkv6_forward_on_a_v5e_mesh_leaves_its_projections_to_xla(
+        topo, one_chip, kernels_for_tpu):
+    """On a mesh the partitioner cannot split a Pallas call (it refuses
+    one), so RWKV-6 keeps XLA's projections there.  The forward on a
+    described 2x2 v5e, batch over 'data' and the projections over
+    'model' (the serving layout), compiles with no kernel, and gathers
+    neither a weight stack nor the whole batch onto a chip."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import SHAPES_BY_NAME, get_config, reduced
+    from repro.launch.specs import input_specs
+    from repro.models import actshard, rwkv6
+    from repro.models.params import ParamDef
+    from repro.runtime import batch_pspecs, model_param_pspecs
+
+    cfg = reduced(get_config("rwkv6-1.6b"))
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    defs = rwkv6.param_defs(cfg)
+    params = jax.tree.map(
+        lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32), defs,
+        is_leaf=lambda d: isinstance(d, ParamDef))
+    batch = input_specs(cfg, dataclasses.replace(
+        SHAPES_BY_NAME["train_4k"], seq_len=32, global_batch=4))
+
+    def named(tree):
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
+                            is_leaf=lambda s: isinstance(s, P))
+
+    actshard.set_mesh(mesh, "tp")
+    try:
+        fwd = jax.jit(
+            lambda p, b: rwkv6.forward(cfg, p, b)[0],
+            in_shardings=(named(model_param_pspecs(cfg, mesh, defs,
+                                                   profile="tp")),
+                          named(batch_pspecs(cfg, mesh, batch, "tp"))))
+        hlo = fwd.lower(params, batch).compile().as_text()
+    finally:
+        actshard.set_mesh(None)
+
+    assert "tpu_custom_call" not in hlo
+    weights = {tuple(s.shape[i:]) for i in (0, 1)
+               for g, names in rwkv6.STACKED.items()
+               for s in (params["blocks"][g][n] for n in names)}
+    gathered = [tuple(int(d) for d in dims.split(","))
+                for line in hlo.splitlines()
+                if re.search(r" all-gather(-start)?\(", line)
+                for dims in re.findall(r"\w+\[([\d,]+)\]",
+                                       line.split(" all-gather")[0])]
+    assert gathered                  # the features of the split heads
+    assert all(shape[0] == 2 for shape in gathered)    # half the batch
+    assert not weights & set(gathered)
