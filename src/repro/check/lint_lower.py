@@ -168,6 +168,14 @@ def lint_doc(doc: dict,
             _check_ragged(key, "k", bk, seq, ragged, findings)
         elif kernel == "rwkv_chunk":
             scan = by_name[parts[0]]
+            if scan.op != "scan" or scan.scan_kind != "wkv":
+                # the kernel computes WKV (per-channel decay, bonus):
+                # on any other recurrence it is a wrong answer
+                findings.append(Finding(
+                    "lint.scan_kind", key,
+                    f"rwkv_chunk on a {scan.op} layer of kind"
+                    f" {scan.scan_kind!r}; it computes 'wkv' only"))
+                continue
             for param, want in (("bh", scan.b), ("t", scan.ox),
                                 ("k", scan.c), ("v", scan.k)):
                 if int(val.get(param, want)) != want:

@@ -18,6 +18,7 @@ from repro.configs.base import (
 
 from repro.configs import (  # noqa: E402
     edgenext_s,
+    granite_4_0_h_micro,
     h2o_danube_1_8b,
     minitron_4b,
     olmo_1b,
@@ -41,6 +42,7 @@ ARCHS = {
     "rwkv6-1.6b": rwkv6_1_6b.CONFIG,
     "seamless-m4t-large-v2": seamless_m4t_large_v2.CONFIG,
     "qwen2-vl-2b": qwen2_vl_2b.CONFIG,
+    "granite-4.0-h-micro": granite_4_0_h_micro.CONFIG,
 }
 
 EDGENEXT_S = edgenext_s.CONFIG

@@ -44,6 +44,7 @@ class ModelConfig:
     - ``hybrid`` : RG-LRU + local-attention (RecurrentGemma)
     - ``ssm``    : RWKV-6 attention-free
     - ``audio``  : encoder-decoder backbone (Seamless-M4T)
+    - ``mamba_hybrid`` : Mamba-2 + full GQA attention (Granite 4.0-H)
     """
 
     name: str
@@ -81,6 +82,24 @@ class ModelConfig:
     # ssm (RWKV-6)
     wkv_head_dim: int = 64
     wkv_chunk: int = 64
+
+    # Mamba-2 layers (mamba_hybrid; HF ``granitemoehybrid`` names): SSD
+    # heads and their width, the state size, B/C groups, the SSD's
+    # chunk; the causal conv's width is ``conv1d_width``
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+
+    # muP multipliers (Granite): embeddings times, each block's output
+    # times onto the residual, the softmax scale (None: head_dim**-0.5),
+    # logits divided by
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    rms_norm_eps: float = 1e-6
 
     # encoder-decoder (Seamless)
     num_encoder_layers: int = 0
@@ -126,8 +145,8 @@ class ModelConfig:
         assert self.num_heads % max(self.num_kv_heads, 1) == 0, self.name
         if self.moe.enabled:
             assert self.moe.num_experts_padded >= self.moe.num_experts
-        if self.family == "hybrid":
-            assert self.block_pattern, "hybrid family needs a block_pattern"
+        if self.family in ("hybrid", "mamba_hybrid"):
+            assert self.block_pattern, f"{self.family} needs a block_pattern"
         if self.rope == "mrope":
             assert sum(self.mrope_sections) * 2 == self.head_dim
 
@@ -195,8 +214,13 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     n_layers = min(cfg.num_layers, 2)
     pattern = cfg.block_pattern
     if pattern:
-        pattern = pattern[: max(3, n_layers)]
+        # the shortest prefix, of at least three layers, that holds a
+        # layer of every kind the pattern has
+        end = max(pattern.index(kind) for kind in set(pattern)) + 1
+        pattern = pattern[: max(3, n_layers, end)]
         n_layers = len(pattern)
+    mamba = dict(mamba_n_heads=4, mamba_d_head=32, mamba_d_state=16,
+                 mamba_chunk_size=8) if cfg.mamba_n_heads else {}
     return dataclasses.replace(
         cfg,
         num_layers=n_layers,
@@ -215,6 +239,7 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         num_encoder_layers=min(cfg.num_encoder_layers, 2),
         mrope_sections=(2, 3, 3) if cfg.rope == "mrope" else (),
         dtype="float32",
+        **mamba,
     )
 
 

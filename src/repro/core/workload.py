@@ -57,6 +57,10 @@ class Layer:
     # graph role annotations used by the fusion planner
     ibn_role: Optional[str] = None   # "expand" | "act" | "project"
     ibn_id: int = -1                 # groups the three IBN layers
+    # SCAN only, the recurrence: "wkv" (per-channel decay with a bonus,
+    # ``rwkv_chunk``'s; the RG-LRU is lowered as one too, ROADMAP 2.3)
+    # or "ssd" (Mamba-2: a scalar decay per head, no kernel yet)
+    scan_kind: str = "wkv"
 
     @property
     def signature(self) -> str:
@@ -66,9 +70,12 @@ class Layer:
         which the search consults.  Two layers with equal signatures are
         interchangeable to every scheduler decision, which is what the
         unique-layer memo (``search.memo``) and the schedule cache key
-        (``search.cache.schedule_key``) rely on."""
+        (``search.cache.schedule_key``) rely on.  A SCAN's recurrence
+        decides its lowering, so a kind other than "wkv" is hashed too
+        (the "wkv" scans keep the signatures they had)."""
         return _layer_signature(self.op, self.b, self.k, self.c, self.ox,
-                                self.oy, self.fx, self.fy, self.bits)
+                                self.oy, self.fx, self.fy, self.bits,
+                                self.scan_kind if self.op == SCAN else "wkv")
 
     @property
     def macs(self) -> int:
@@ -130,8 +137,10 @@ class Layer:
 
 @functools.lru_cache(maxsize=None)
 def _layer_signature(op: str, b: int, k: int, c: int, ox: int, oy: int,
-                     fx: int, fy: int, bits: int) -> str:
+                     fx: int, fy: int, bits: int, scan_kind: str) -> str:
     blob = f"{op}:{b}:{k}:{c}:{ox}:{oy}:{fx}:{fy}:{bits}"
+    if scan_kind != "wkv":
+        blob += f":{scan_kind}"
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
 
 
@@ -719,6 +728,69 @@ def recurrentgemma_workload(*, seq: int = 448, n_layers: int = 26,
                                 c=heads * head_dim, ox=t))
         layers.append(Layer(f"{p}.res1", ELEMWISE, b=batch, c=dim, ox=t))
         mlp(p, bi)
+    layers.append(Layer("head.ln", NORM, b=batch, c=dim, ox=t))
+    return layers
+
+
+def granite_workload(*, seq: int = 8192, n_layers: int = 40,
+                     dim: int = 2048, heads: int = 32, kv_heads: int = 8,
+                     head_dim: int = 64, ff: int = 8192,
+                     ssm_heads: int = 64, ssm_head_dim: int = 64,
+                     d_state: int = 128, conv1d_width: int = 4,
+                     batch: int = 1) -> List[Layer]:
+    """Granite 4.0-H Micro blocks (configs/granite_4_0_h_micro.py dims)
+    at a prefill sequence length: layer ``i`` is GQA attention when
+    ``i % 10 == 5``, Mamba-2 otherwise, each followed by a SwiGLU MLP.
+
+    Mamba-2: one in_proj GEMM (z, x, B, C, dt), the width-4 causal conv
+    over x, B and C (a 1-D DWCONV), the SSD as a SCAN of kind "ssd" with
+    a [d_state, head_dim] state per head (B and C shared by all heads),
+    the gated RMSNorm and the out projection.  Attention: one q/k/v GEMM,
+    the causal score and value matmuls over ``heads`` query heads, the
+    output projection.  The MLP's up GEMM makes gate and up together
+    (2 x ``ff``).  The LM head is omitted (see ``rwkv6_workload``).
+    """
+    layers: List[Layer] = []
+    t = seq
+    inner = ssm_heads * ssm_head_dim
+    for bi in range(n_layers):
+        p = f"blk{bi}"
+        layers.append(Layer(f"{p}.ln1", NORM, b=batch, c=dim, ox=t))
+        if bi % 10 == 5:
+            layers.append(Layer(f"{p}.attn.qkv", PWCONV, b=batch,
+                                k=(heads + 2 * kv_heads) * head_dim, c=dim,
+                                ox=t))
+            layers.append(Layer(f"{p}.attn.qk", MATMUL, b=batch * heads,
+                                k=t, c=head_dim, ox=t))
+            layers.append(Layer(f"{p}.attn.sm", SOFTMAX, b=batch * heads,
+                                c=t, ox=t))
+            layers.append(Layer(f"{p}.attn.av", MATMUL, b=batch * heads,
+                                k=head_dim, c=t, ox=t))
+            layers.append(Layer(f"{p}.attn.proj", PWCONV, b=batch, k=dim,
+                                c=heads * head_dim, ox=t))
+        else:
+            layers.append(Layer(f"{p}.mamba.in_proj", PWCONV, b=batch,
+                                k=2 * inner + 2 * d_state + ssm_heads,
+                                c=dim, ox=t))
+            layers.append(Layer(f"{p}.mamba.conv", DWCONV, b=batch,
+                                c=inner + 2 * d_state, ox=t,
+                                fx=conv1d_width))
+            layers.append(Layer(f"{p}.mamba.ssd", SCAN, b=batch * ssm_heads,
+                                ox=t, c=d_state, k=ssm_head_dim,
+                                scan_kind="ssd"))
+            layers.append(Layer(f"{p}.mamba.gnorm", NORM, b=batch, c=inner,
+                                ox=t))
+            layers.append(Layer(f"{p}.mamba.out_proj", PWCONV, b=batch,
+                                k=dim, c=inner, ox=t))
+        layers.append(Layer(f"{p}.res1", ELEMWISE, b=batch, c=dim, ox=t))
+        layers.append(Layer(f"{p}.ln2", NORM, b=batch, c=dim, ox=t))
+        layers.append(Layer(f"{p}.mlp.up", PWCONV, b=batch, k=2 * ff, c=dim,
+                            ox=t, ibn_role="expand", ibn_id=5000 + bi))
+        layers.append(Layer(f"{p}.mlp.act", ACT, b=batch, c=ff, ox=t,
+                            ibn_role="act", ibn_id=5000 + bi))
+        layers.append(Layer(f"{p}.mlp.down", PWCONV, b=batch, k=dim, c=ff,
+                            ox=t, ibn_role="project", ibn_id=5000 + bi))
+        layers.append(Layer(f"{p}.res2", ELEMWISE, b=batch, c=dim, ox=t))
     layers.append(Layer("head.ln", NORM, b=batch, c=dim, ox=t))
     return layers
 

@@ -11,7 +11,8 @@ Every module exposes the same functional surface:
 from __future__ import annotations
 
 from repro.configs.base import ModelConfig
-from repro.models import recurrentgemma, rwkv6, seamless, transformer
+from repro.models import (mamba_hybrid, recurrentgemma, rwkv6, seamless,
+                          transformer)
 
 FAMILY_MODULES = {
     "dense": transformer,
@@ -20,6 +21,7 @@ FAMILY_MODULES = {
     "hybrid": recurrentgemma,
     "ssm": rwkv6,
     "audio": seamless,
+    "mamba_hybrid": mamba_hybrid,
 }
 
 

@@ -6,6 +6,10 @@ matmul streams block-by-block, so the [Sq, Sk] score intermediate never
 materializes in HBM — the online-softmax state (m, l, acc) is the TPU
 analogue of the paper's writeback line buffer.
 
+The online-softmax loop opens the scopes ``qk``, ``sm`` and ``av``
+around the scores, the softmax statistics and the value product, so a
+model's layer scopes (``repro.obs.layers``) can split its attention.
+
 Three entry points:
 
 - ``flash_attention``        : fwd+bwd (custom_vjp), causal/window masks, full scan
@@ -72,17 +76,20 @@ def _flash_fwd(q, k, v, causal: bool, window: Optional[int], scale: float,
 
         def kv_step(carry, j):
             m, l, acc = carry
-            kj = kr[:, :, j].astype(jnp.float32)           # [B,H,bk,D]
-            vj = vr[:, :, j].astype(jnp.float32)
-            s = jnp.einsum("bhqd,bhkd->bhqk", qi, kj)      # [B,H,bq,bk]
-            mask = _block_mask(i * bq, j * bk, bq, bk, causal, window)
-            s = jnp.where(mask[None, None], s, NEG_INF)
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            p = jnp.exp(s - m_new[..., None])
-            alpha = jnp.exp(m - m_new)
-            l_new = l * alpha + p.sum(axis=-1)
-            acc_new = acc * alpha[..., None] + jnp.einsum(
-                "bhqk,bhkd->bhqd", p, vj)
+            with jax.named_scope("qk"):
+                kj = kr[:, :, j].astype(jnp.float32)       # [B,H,bk,D]
+                s = jnp.einsum("bhqd,bhkd->bhqk", qi, kj)  # [B,H,bq,bk]
+                mask = _block_mask(i * bq, j * bk, bq, bk, causal, window)
+                s = jnp.where(mask[None, None], s, NEG_INF)
+            with jax.named_scope("sm"):
+                m_new = jnp.maximum(m, s.max(axis=-1))
+                p = jnp.exp(s - m_new[..., None])
+                alpha = jnp.exp(m - m_new)
+                l_new = l * alpha + p.sum(axis=-1)
+            with jax.named_scope("av"):
+                vj = vr[:, :, j].astype(jnp.float32)
+                acc_new = acc * alpha[..., None] + jnp.einsum(
+                    "bhqk,bhkd->bhqd", p, vj)
             return (m_new, l_new, acc_new), None
 
         init = (

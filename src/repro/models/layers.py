@@ -45,7 +45,8 @@ def norm_apply(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
     xf = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
         var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * lax.rsqrt(var + 1e-6) * params["scale"].astype(jnp.float32)
+        y = xf * lax.rsqrt(var + cfg.rms_norm_eps) * \
+            params["scale"].astype(jnp.float32)
     else:
         mean = jnp.mean(xf, axis=-1, keepdims=True)
         var = jnp.var(xf, axis=-1, keepdims=True)

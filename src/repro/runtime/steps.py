@@ -117,7 +117,8 @@ def build_prefill_step(cfg: ModelConfig, *, use_flash: bool = True,
 
     def prefill_step(params, batch):
         kw = {}
-        if cfg.family == "audio" and decode_len is not None:
+        if cfg.family in ("audio", "mamba_hybrid") and \
+                decode_len is not None:
             kw["decode_len"] = decode_len
         return mod.prefill(cfg, params, batch, use_flash=use_flash,
                            scan_unroll=scan_unroll, **kw)

@@ -50,7 +50,8 @@ def get_workload(name: str):
     from repro.configs.edgenext_s import CONFIG, reduced_edgenext
     from repro.core.workload import (edgenext_workload,
                                      efficientvit_workload,
-                                     fastvit_workload, mobilevit_workload,
+                                     fastvit_workload, granite_workload,
+                                     mobilevit_workload,
                                      recurrentgemma_workload,
                                      rwkv6_workload, vit_workload,
                                      with_batch)
@@ -63,6 +64,7 @@ def get_workload(name: str):
         "fastvit-s": lambda: fastvit_workload(),
         "rwkv6": lambda: rwkv6_workload(),
         "recurrentgemma": lambda: recurrentgemma_workload(),
+        "granite-h-micro": lambda: granite_workload(),
     }
     base, batch = parse_workload(name)
     if base not in builders:
@@ -91,7 +93,8 @@ def parse_workload(name: str) -> tuple:
 # open more (``OUTSIDE_CHAIN_SCOPES``)
 FORWARDS = {"edgenext-s": "repro.models.edgenext",
             "edgenext-reduced": "repro.models.edgenext",
-            "rwkv6": "repro.models.rwkv6"}
+            "rwkv6": "repro.models.rwkv6",
+            "granite-h-micro": "repro.models.mamba_hybrid"}
 
 
 def layer_scopes(name: str) -> dict:
@@ -113,4 +116,5 @@ def layer_scopes(name: str) -> dict:
 
 WORKLOADS = ("edgenext-s", "edgenext-s-b4", "edgenext-reduced", "vit-tiny",
              "efficientvit-b0", "mobilevit-s", "mobilevit-s-b4",
-             "fastvit-s", "fastvit-s-b4", "rwkv6", "recurrentgemma")
+             "fastvit-s", "fastvit-s-b4", "rwkv6", "recurrentgemma",
+             "granite-h-micro")

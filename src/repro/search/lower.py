@@ -153,14 +153,19 @@ def lower_attention(qk: Layer, *, tile_x: int,
                          {"q": rq, "k": rk})
 
 
-def lower_scan(scan: Layer, tinfo: Dict[str, int]) -> LoweredKernel:
-    """Chunked-recurrence layer -> rwkv_chunk(chunk): the searched chunk
+def lower_scan(scan: Layer, tinfo: Dict[str, int]
+               ) -> Optional[LoweredKernel]:
+    """Chunked-recurrence layer -> rwkv_chunk(chunk), for a scan of kind
+    "wkv" only: no kernel computes Mamba-2's scalar-decay SSD ("ssd"), so
+    it lowers to nothing and counts as unlowered.  The searched chunk
     length IS the kernel's sequence block.  Unlike the GEMM kernels the
     chunk is not re-snapped here — the search already restricted itself
     to the pow2 chunk menu, and the carry makes the grid order
     non-negotiable (chunks run sequentially).  A non-dividing final
     chunk is reported via ``ragged["t"]``; the ops wrapper pads T and
     the kernel masks the padded tail in-kernel."""
+    if scan.scan_kind != "wkv":
+        return None
     chunk = max(1, min(int(tinfo.get("chunk") or 64), scan.ox))
     ragged = {"t": scan.ox % chunk} if scan.ox % chunk else {}
     return LoweredKernel("rwkv_chunk", (scan.name,),
@@ -190,7 +195,9 @@ def lower_schedule(layers: Sequence[Layer], groups, tiles: Dict[str, dict],
         sl = layers[g.start:g.end]
         scan = next((l for l in sl if l.op == SCAN), None)
         if scan is not None:
-            out.append(lower_scan(scan, tiles.get(scan.name, {})))
+            lk = lower_scan(scan, tiles.get(scan.name, {}))
+            if lk is not None:
+                out.append(lk)
             continue
         macs = [l for l in sl if l.op in MAC_OPS]
         names = {l.name for l in sl}

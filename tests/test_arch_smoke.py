@@ -23,6 +23,7 @@ ARCH_IDS = sorted(ARCHS)
 # encoder-decoder compiles) run only in the slow lane; the cheap archs
 # keep per-family train coverage in the default run
 _HEAVY = {"recurrentgemma-2b", "seamless-m4t-large-v2", "rwkv6-1.6b",
+          "granite-4.0-h-micro",
           "h2o-danube-1.8b", "qwen2-moe-a2.7b", "qwen3-moe-30b-a3b"}
 TRAIN_ARCH_IDS = [
     pytest.param(a, marks=pytest.mark.slow) if a in _HEAVY else a
@@ -83,6 +84,21 @@ def test_prefill_decode_smoke(arch):
     assert logits.shape == (2, cfg.padded_vocab)
     assert np.isfinite(np.asarray(logits[:, :cfg.vocab_size])).all()
     assert (np.asarray(tok1) < cfg.vocab_size).all()
+
+
+@pytest.mark.parametrize(
+    "arch", [a for a in ARCH_IDS if ARCHS[a].block_pattern])
+def test_reduced_pattern_keeps_every_layer_kind(arch):
+    """The smoke variant builds a layer of every kind the pattern has
+    (Granite's first attention layer is its sixth), and RecurrentGemma's
+    stays its first period."""
+    cfg = get_config(arch)
+    small = reduced(cfg)
+    assert set(small.block_pattern) == set(cfg.block_pattern)
+    assert small.block_pattern == cfg.block_pattern[:small.num_layers]
+    if arch == "recurrentgemma-2b":
+        assert small.block_pattern == ("recurrent", "recurrent",
+                                       "attention")
 
 
 def test_full_configs_validate():

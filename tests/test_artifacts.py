@@ -1,6 +1,6 @@
 """Deliverable guards over the committed dry-run artifacts: every
 (arch x applicable shape) cell must have compiled on BOTH production
-meshes (33 + 33), with roofline-complete records.  Skips cleanly if the
+meshes (36 + 36), with roofline-complete records.  Skips cleanly if the
 artifact directory has not been generated yet."""
 import json
 from pathlib import Path

@@ -11,7 +11,8 @@ The load-bearing claims of the scan subsystem:
     nonlinear tail may cross the chunk boundary only when the [K, V]
     carry state fits a local-level budget;
   * lowering emits real ``rwkv_chunk`` launch params with the searched
-    chunk as the block size (ragged final chunk reported explicitly);
+    chunk as the block size (ragged final chunk reported explicitly),
+    for WKV scans only: Mamba-2's scalar-decay SSD lowers to nothing;
   * the Pallas kernel agrees with the model-level chunked WKV.
 """
 import jax
@@ -194,6 +195,41 @@ def test_lowered_rwkv_chunk_params():
                 (l.b, l.ox, l.c, l.k)
             want_ragged = l.ox % lk["chunk"]
             assert lk.get("ragged", {}).get("t", 0) == want_ragged
+
+
+def test_ssd_scan_lowers_to_no_kernel():
+    """No kernel computes Mamba-2's scalar-decay SSD: its scans emit no
+    launch and each counts as an unlowered group, where ``rwkv_chunk``
+    (WKV with a bonus) would compute another recurrence."""
+    from repro import obs
+    from repro.search import lower
+    wl = get_workload("granite-h-micro")
+    ssd = [l for l in wl if l.op == SCAN]
+    assert len(ssd) == 36 and {l.scan_kind for l in ssd} == {"ssd"}
+    assert lower.lower_scan(ssd[0], {"chunk": 256}) is None
+    wkv_alike = Layer(ssd[0].name, SCAN, b=ssd[0].b, k=ssd[0].k,
+                      c=ssd[0].c, ox=ssd[0].ox)
+    assert wkv_alike.signature != ssd[0].signature
+    with obs.tracing() as t:
+        sched = auto_schedule(wl, HW, workload="granite-h-micro")
+    assert not {n for n, lk in sched.lowered.items()
+                if lk["kernel"] == "rwkv_chunk"}
+    assert not {n for n in sched.lowered if ".mamba.ssd" in n}
+    assert t.counters["lower.groups_unlowered"] >= len(ssd)
+    assert "lower.kernel.rwkv_chunk" not in t.counters
+
+
+def test_lint_refuses_rwkv_chunk_on_an_ssd_scan():
+    from repro.check import lint_doc
+    wl = get_workload("granite-h-micro")
+    ssd = next(l for l in wl if l.op == SCAN)
+    forged = {"kernel": "rwkv_chunk", "chunk": 256, "bh": ssd.b,
+              "t": ssd.ox, "k": ssd.c, "v": ssd.k}
+    found = lint_doc({"lowered": {ssd.name: forged}}, wl)
+    assert [f.code for f in found] == ["lint.scan_kind"]
+    wkv = next(l for l in RWKV_WL if l.op == SCAN)
+    assert lint_doc({"lowered": {wkv.name: RWKV_SCHED.lowered[wkv.name]}},
+                    RWKV_WL) == []
 
 
 def test_recurrentgemma_seq_is_ragged():

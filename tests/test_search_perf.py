@@ -196,7 +196,7 @@ def test_signature_field_lists_track_the_dataclasses():
     from repro.core.memory import MemoryLevel
     assert {f.name for f in dataclasses.fields(Layer)} == {
         "name", "op", "b", "k", "c", "ox", "oy", "fx", "fy", "bits",
-        "ibn_role", "ibn_id"}, \
+        "ibn_role", "ibn_id", "scan_kind"}, \
         "Layer grew a field: update workload._layer_signature"
     assert {f.name for f in dataclasses.fields(HWSpec)} == {
         "rows", "cols", "clock_hz", "bits", "e_mac", "static_mw",

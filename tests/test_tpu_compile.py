@@ -177,6 +177,37 @@ def test_rwkv6_forward_streams_its_weight_stacks(one_chip, kernels_for_tpu):
     assert all("/while/body/" in line for line in kernels)
 
 
+def test_granite_prefill_compiles_for_v5e_and_fits_one_chip(
+        one_chip, kernels_for_tpu):
+    """The served Granite 4.0-H Micro forward at full width and the
+    cell's 8192 tokens (bf16 weights): every projection of the unrolled
+    period (9 Mamba-2 x 4, one attention x 4, 10 MLPs x 2) is a
+    ``stacked_proj`` kernel in the layer scan, and weights plus
+    temporaries fit one v5e's 16 GB."""
+    from repro.configs.granite_4_0_h_micro import CONFIG
+    from repro.models import mamba_hybrid
+    from repro.models.params import ParamDef
+
+    params = jax.tree.map(
+        lambda d: jax.ShapeDtypeStruct(d.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        mamba_hybrid.param_defs(CONFIG),
+        is_leaf=lambda d: isinstance(d, ParamDef))
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+
+    def served(p, t):
+        hidden, _ = mamba_hybrid.forward(CONFIG, p, {"tokens": t})
+        return mamba_hybrid.logits_fn(CONFIG, p, hidden[:, -1:, :])
+
+    compiled = jax.jit(served).lower(params, tokens).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 60
+    assert all("/while/body/" in line for line in kernels)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
 def test_stacked_proj_compiles_for_v5e_at_decodes_one_row(one_chip):
     """cmix.value's projection, the widest K, from a [24, 7168, 2048] f32
     stack at decode's M = 1 (the forward above runs M = 512)."""
