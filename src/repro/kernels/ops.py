@@ -184,12 +184,52 @@ def depthwise_conv2d(x: jax.Array, w: jax.Array, b: jax.Array, *,
     return out[..., :C]
 
 
+# the WKV kernel's step, chosen on a v5e at RWKV-6 1.6B's 512-token
+# prefill (tools/tune_wkv.py, PERF.md section 6): eight heads of a chunk
+# a step, chunks on the grid, sub-chunks of 16.  All 32 heads a step run
+# 10% faster but unroll 16 lane groups, which costs ~3 s of tracing and
+# Mosaic compiling in set-up.
+_WKV_HEADS, _WKV_CHUNKS, _WKV_SUB = 8, 1, 16
+
+
 def wkv_chunked(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
                 u: jax.Array, *, chunk: int = 64,
                 interpret: Optional[bool] = None):
-    """Chunked WKV at any T: the kernel pads T to a chunk multiple and
-    masks the ragged tail in-kernel, so the requested chunk is honored
+    """Chunked WKV over per-head rows: r,k,logw: [BH, T, K]; v: [BH, T,
+    V = K]; u: [BH, K], from a zero state.  Returns (out [BH, T, V],
+    final state [BH, K, V] f32).  At any T: the kernel pads T and masks
+    the ragged tail in-kernel, so the requested chunk is honored
     verbatim (it is the searched schedule parameter, never shrunk)."""
     interp = (not _on_tpu()) if interpret is None else interpret
-    return _wkv.wkv_chunked(r, k, v, logw, u, chunk=chunk,
-                            interpret=interp)
+    BH, T, K = r.shape
+    out, state = _wkv.wkv(r, k, v, logw, u[:, None, :],
+                          jnp.zeros((BH, 1, K, K), jnp.float32), K=K,
+                          chunk=chunk, heads_per_step=1,
+                          chunks_per_step=_WKV_CHUNKS, sub=_WKV_SUB,
+                          interpret=interp)
+    return out, state[:, 0].swapaxes(-1, -2)
+
+
+def wkv(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
+        u: jax.Array, state: jax.Array, *, chunk: int,
+        interpret: Optional[bool] = None):
+    """Chunked WKV in the model's layout: r, k, v, logw: [B, T, H, K]
+    (read as the projections wrote them, [B, T, H*K]); u: [H, K]; state:
+    [B, H, K, V = K] f32.  Returns (out [B, T, H, K] in r's dtype, final
+    state [B, H, K, K] f32).  The chunk is C verbatim, as above."""
+    interp = (not _on_tpu()) if interpret is None else interpret
+    B, T, H, K = r.shape
+    G = _wkv.group_width(H, K)
+    heads = max(_WKV_HEADS, G // K)
+    if H % heads or (heads * K) % _LANE:
+        heads = H
+    flat = [x.reshape(B, T, H * K) for x in (r, k, v)]
+    out, groups = _wkv.wkv(
+        *flat, logw.reshape(B, T, H * K).astype(jnp.float32),
+        jnp.broadcast_to(u.reshape(1, 1, H * K), (B, 1, H * K)).astype(
+            jnp.float32),
+        _wkv.state_to_groups(state.astype(jnp.float32), G), K=K,
+        chunk=chunk, heads_per_step=heads, chunks_per_step=_WKV_CHUNKS,
+        sub=_WKV_SUB, interpret=interp)
+    return (out.reshape(B, T, H, K),
+            _wkv.state_from_groups(groups, K))

@@ -71,9 +71,10 @@ def depthwise_conv2d_ref(x, w, b):
     return (y + b.astype(jnp.float32)).astype(x.dtype)
 
 
-def wkv_ref(r, k, v, logw, u):
+def wkv_ref(r, k, v, logw, u, state=None):
     """Naive per-token WKV6 recurrence.  r,k,logw: [BH,T,K]; v: [BH,T,V];
-    u: [BH,K].  Returns (out [BH,T,V], final_state [BH,K,V] f32)."""
+    u: [BH,K]; state: [BH,K,V], zeros if None.  Returns (out [BH,T,V],
+    final_state [BH,K,V] f32)."""
     BH, T, K = r.shape
     V = v.shape[-1]
     rf = r.astype(jnp.float32)
@@ -90,6 +91,7 @@ def wkv_ref(r, k, v, logw, u):
         S = jnp.exp(wt)[..., None] * S + at
         return S, out_t
 
-    S0 = jnp.zeros((BH, K, V), jnp.float32)
+    S0 = (jnp.zeros((BH, K, V), jnp.float32) if state is None
+          else state.astype(jnp.float32))
     S, outs = lax.scan(step, S0, jnp.arange(T))
     return outs.transpose(1, 0, 2).astype(r.dtype), S

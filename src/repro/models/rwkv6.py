@@ -3,7 +3,9 @@
 TPU adaptation: the WKV6 recurrence (data-dependent diagonal decay) is
 executed in *chunked* form — within a chunk of C tokens the recurrence is
 re-expressed as three MXU matmuls plus a C×C intra-chunk score matrix, and the
-[K,V] state is carried across chunks with a scan.  All decay factors appear as
+[K,V] state is carried across chunks: on one device in VMEM, by the Pallas
+kernel ``kernels.rwkv_chunk``; under a mesh, and for the gradient, by
+``wkv_chunked_xla``'s scan.  All decay factors appear as
 ``exp(b_t - b_s)`` with ``t >= s`` and ``b`` a running cumsum of log-decays
 (always <= 0), so every exponent is <= 0 — numerically safe without
 renormalization.
@@ -35,6 +37,7 @@ the stacks and the whole batch onto every chip to run it.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
@@ -134,8 +137,42 @@ def param_defs(cfg: ModelConfig) -> Params:
 def wkv_chunked(r, k, v, logw, u, state, chunk: int):
     """r,k,logw: [B,T,H,K]; v: [B,T,H,V]; u: [H,K]; state: [B,H,K,V].
 
-    Returns (out [B,T,H,V], new_state).  logw = log(decay) <= 0.
+    Returns (out [B,T,H,V], new_state).  logw = log(decay) <= 0.  On one
+    device this is the Pallas kernel ``kernels.rwkv_chunk``, which reads
+    the operands as the projections wrote them and keeps each head's
+    state in VMEM across the chunks; its gradient is that of
+    ``wkv_chunked_xla``.  Under an ``actshard`` mesh it is
+    ``wkv_chunked_xla``: the partitioner cannot split a Pallas call.
     """
+    if actshard.current_mesh() is not None:
+        return wkv_chunked_xla(r, k, v, logw, u, state, chunk)
+    return _wkv_kernel(r, k, v, logw, u, state, chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _wkv_kernel(r, k, v, logw, u, state, chunk):
+    # imported where the WKV is traced, as in ``_proj``
+    from repro.kernels import ops
+    return ops.wkv(r, k, v, logw, u, state, chunk=chunk)
+
+
+def _wkv_kernel_fwd(r, k, v, logw, u, state, chunk):
+    return (_wkv_kernel(r, k, v, logw, u, state, chunk),
+            (r, k, v, logw, u, state))
+
+
+def _wkv_kernel_bwd(chunk, res, g):
+    """The XLA form's gradient, its forward recomputed."""
+    _, vjp = jax.vjp(lambda *a: wkv_chunked_xla(*a, chunk), *res)
+    return vjp(g)
+
+
+_wkv_kernel.defvjp(_wkv_kernel_fwd, _wkv_kernel_bwd)
+
+
+def wkv_chunked_xla(r, k, v, logw, u, state, chunk: int):
+    """``wkv_chunked`` as XLA's chunked form: the chunks in a
+    ``lax.scan``, the intra-chunk scores as a [B, H, C, C, K] tensor."""
     B, T, H, K = r.shape
     V = v.shape[-1]
     C = min(chunk, T)

@@ -335,6 +335,88 @@ def test_wkv_chunk_invariance():
                                rtol=2e-4, atol=2e-4)
 
 
+def _wkv_model_inputs(B, T, H, K, dtype, decay_scale):
+    """r, k, v (dtype), logw (f32), u, a non-zero initial state, in the
+    model's layout [B, T, H, K]."""
+    ks = jax.random.split(KEY, 6)
+    r, k, v = (jax.random.normal(kk, (B, T, H, K)).astype(dtype) * 0.5
+               for kk in ks[:3])
+    logw = -jnp.exp(jax.random.normal(ks[3], (B, T, H, K)) * decay_scale)
+    u = jax.random.normal(ks[4], (H, K)) * 0.5
+    state = jax.random.normal(ks[5], (B, H, K, K)) * 0.3
+    return r, k, v, logw, u, state
+
+
+@pytest.mark.parametrize("B,T,H,K,chunk,dtype,decay_scale,tol,steps", [
+    # four heads of 64 a block, two lane groups; T = 50 ragged at chunk 16
+    (2, 50, 4, 64, 16, jnp.float32, 0.5, 2e-4, None),
+    # the same two heads a block and two chunks a step: the chunk loop
+    # inside a grid step, and a ragged step
+    (2, 50, 4, 64, 16, jnp.float32, 0.5, 2e-4, (2, 2)),
+    # chunk 64 in sub-chunks; log-decays down to -20 a token, so the
+    # sub-chunk factors underflow where the true scores do
+    (1, 128, 2, 64, 64, jnp.float32, 1.2, 2e-4, None),
+    # bf16 r/k/v and f32 logw, as the model feeds them: one bf16 MXU
+    # pass, as XLA's chunked form takes on a TPU
+    (1, 128, 4, 64, 64, jnp.bfloat16, 0.5, 1e-2, None),
+], ids=["ragged-t-state", "two-chunks-a-step", "strong-decay", "bf16"])
+def test_wkv_kernel_in_model_layout(B, T, H, K, chunk, dtype, decay_scale,
+                                    tol, steps, monkeypatch):
+    """ops.wkv (the kernel on [B, T, H*K] operands, several heads a
+    block, from a non-zero state) == ref.wkv_ref per head == the model's
+    XLA chunked form.  ``steps``: (heads, chunks) a grid step in place of
+    the tuned ones."""
+    from repro.models import rwkv6
+    if steps:
+        monkeypatch.setattr(ops, "_WKV_HEADS", steps[0])
+        monkeypatch.setattr(ops, "_WKV_CHUNKS", steps[1])
+    r, k, v, logw, u, state = _wkv_model_inputs(B, T, H, K, dtype,
+                                                decay_scale)
+    if decay_scale > 1:
+        assert float(logw.min()) < -20
+    out, st = ops.wkv(r, k, v, logw, u, state, chunk=chunk)
+    assert out.dtype == dtype and out.shape == (B, T, H, K)
+
+    def per_head(x):                     # [B,T,H,K] -> [BH,T,K]
+        return x.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(
+            B * H, T, K)
+    want, st_want = ref.wkv_ref(per_head(r), per_head(k), per_head(v),
+                                per_head(logw), jnp.tile(u, (B, 1)),
+                                state.reshape(B * H, K, K))
+    xla, st_xla = rwkv6.wkv_chunked_xla(r, k, v, logw, u, state, chunk)
+    scale = float(jnp.abs(want).max())
+    for got_o, got_s in ((out, st), (xla, st_xla)):
+        np.testing.assert_allclose(
+            np.asarray(per_head(got_o)), np.asarray(want),
+            rtol=tol, atol=tol * scale)
+        np.testing.assert_allclose(
+            np.asarray(got_s.reshape(B * H, K, K)), np.asarray(st_want),
+            rtol=tol, atol=tol * float(jnp.abs(st_want).max()))
+
+
+def test_wkv_kernel_gradient_is_the_xla_forms():
+    """The model's kernel path differentiates as XLA's chunked form: its
+    custom VJP recomputes that form's gradient."""
+    from repro.models import rwkv6
+    r, k, v, logw, u, state = _wkv_model_inputs(1, 32, 2, 64, jnp.float32,
+                                                0.5)
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    w_out = jax.random.normal(ks[0], r.shape)
+    w_st = jax.random.normal(ks[1], state.shape)
+
+    def loss(f):
+        def run(*args):
+            out, st = f(*args, 16)
+            return (out * w_out).sum() + (st * w_st).sum()
+        return jax.grad(run, argnums=tuple(range(6)))(r, k, v, logw, u,
+                                                      state)
+
+    for got, want in zip(loss(rwkv6._wkv_kernel),
+                         loss(rwkv6.wkv_chunked_xla)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+
+
 # ---------------------------------------------------------------------------
 # projection by one layer of a stacked weight, cast in VMEM
 # ---------------------------------------------------------------------------

@@ -21,6 +21,8 @@ KEY = jax.random.PRNGKey(3)
 
 
 def test_wkv_chunked_equals_recurrent():
+    """The chunked WKV, as the Pallas kernel (``wkv_chunked`` off a
+    mesh) and as XLA's chunked form, == the per-token recurrence."""
     B, T, H, K = 2, 32, 2, 8
     ks = jax.random.split(KEY, 5)
     r = jax.random.normal(ks[0], (B, T, H, K)) * 0.5
@@ -30,8 +32,6 @@ def test_wkv_chunked_equals_recurrent():
     u = jax.random.normal(ks[4], (H, K)) * 0.5
     state0 = jnp.zeros((B, H, K, K), jnp.float32)
 
-    out_c, state_c = rwkv6.wkv_chunked(r, k, v, logw, u, state0, chunk=8)
-
     state = state0
     outs = []
     for t in range(T):
@@ -39,18 +39,22 @@ def test_wkv_chunked_equals_recurrent():
             r[:, t], k[:, t], v[:, t], logw[:, t], u, state)
         outs.append(o)
     out_r = jnp.stack(outs, axis=1)
-    np.testing.assert_allclose(np.asarray(out_c), np.asarray(out_r),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(state_c), np.asarray(state),
-                               rtol=2e-4, atol=2e-4)
+    for chunked in (rwkv6.wkv_chunked, rwkv6.wkv_chunked_xla):
+        out_c, state_c = chunked(r, k, v, logw, u, state0, chunk=8)
+        np.testing.assert_allclose(np.asarray(out_c), np.asarray(out_r),
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(state_c), np.asarray(state),
+                                   rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("on_mesh", [False, True], ids=["one-device",
                                                       "mesh"])
 def test_rwkv6_projections_are_kernels_off_a_mesh_only(on_mesh):
     """Off a mesh each layer's eight projections are ``stacked_proj``
-    Pallas calls, in the train step's forward and in decode; under an
-    ``actshard`` mesh none is, since the partitioner cannot split one."""
+    Pallas calls, in the train step's forward and in decode, and the
+    train step's WKV is the ``wkv_chunk`` kernel; under an ``actshard``
+    mesh none is, since the partitioner cannot split one.  Decode's one
+    token takes the recurrent step, never the WKV kernel."""
     from repro.models import actshard
     from repro.optim import adamw_init, warmup_cosine
     from repro.runtime import build_train_step
@@ -73,6 +77,8 @@ def test_rwkv6_projections_are_kernels_off_a_mesh_only(on_mesh):
         actshard.set_mesh(None)
     for jaxpr in (train, decode):
         assert ("pallas_call" in str(jaxpr)) is not on_mesh
+    assert ("name=wkv_chunk" in str(train)) is not on_mesh
+    assert "name=wkv_chunk" not in str(decode)
 
 
 def test_rg_lru_scan_equals_stepwise():

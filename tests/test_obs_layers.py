@@ -139,6 +139,12 @@ def test_rwkv6_spells_a_chain_layer_without_its_block(layer, scope):
      "jit(stacked_proj)/pallas_call", "tmix.rkvg"),
     ("jit(f)/while/body/closed_call/checkpoint/cmix/value/"
      "jit(stacked_proj)/while/body/dot_general", "cmix.value"),
+    # the WKV kernel, named ``wkv_chunk``, counts to its scope's layer
+    ("jit(f)/while/body/closed_call/checkpoint/tmix/wkv/jit(wkv)/"
+     "wkv_chunk/pallas_call", "tmix.wkv"),
+    ("jit(f)/while/body/closed_call/checkpoint/tmix/wkv/jit(wkv)/"
+     "wkv_chunk/while/body/while/body/closed_call/closed_call/exp",
+     "tmix.wkv"),
     ("jit(f)/s1.conv0/reshape", None),          # block scope, no layer
     ("jit(f)/s1.conv0/pw9/dot_general", None),  # renamed: missing
     ("jit(f)/pw1", None),                       # a primitive, not a scope

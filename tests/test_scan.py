@@ -258,7 +258,7 @@ def test_kernel_matches_model_wkv():
     logw = -jnp.exp(jax.random.normal(ks[3], (B, T, H, K)) * 0.5)
     u = jax.random.normal(ks[4], (H, K)) * 0.5
     state0 = jnp.zeros((B, H, K, K), jnp.float32)
-    want, st_want = m.wkv_chunked(r, k, v, logw, u, state0, chunk=16)
+    want, st_want = m.wkv_chunked_xla(r, k, v, logw, u, state0, chunk=16)
 
     def flat(x):                                   # [B,T,H,K] -> [BH,T,K]
         return x.transpose(0, 2, 1, 3).reshape(B * H, T, K)

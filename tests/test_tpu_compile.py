@@ -134,6 +134,8 @@ def test_kernel_compiles_for_v5e(case, one_chip, served):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# XLA's intra-chunk scores: an f32 [..., C, C, K] tensor at chunk 64
+_SCORES = re.compile(r"= f32\[(?:\d+,)*64,64,64\]")
 # the weight stacks of the served RWKV-6 and their per-layer slices
 _STACK = re.compile(r"= bf16\[(?:24,|1,)?(?:2048,2048|2048,7168|7168,2048)\]"
                     r"\S* convert\(")
@@ -152,7 +154,8 @@ def test_rwkv6_forward_streams_its_weight_stacks(one_chip, kernels_for_tpu):
     """The served RWKV-6 forward at full width (f32 weights, bf16
     compute, 512 tokens): every projection is a kernel that reads its
     layer from the whole f32 stack, so no cast or slice of a weight stack
-    is left to XLA."""
+    is left to XLA, and the WKV is the ``wkv_chunk`` kernel, so XLA
+    builds no [C, C, K] intra-chunk score tensor."""
     from repro.configs.rwkv6_1_6b import CONFIG
     from repro.models import rwkv6
     from repro.models.params import ParamDef
@@ -171,10 +174,42 @@ def test_rwkv6_forward_streams_its_weight_stacks(one_chip, kernels_for_tpu):
     hlo = jax.jit(served).lower(params, tokens).compile().as_text()
     assert not _STACK.findall(hlo)
     assert not _SLICE.findall(hlo)
+    assert not _SCORES.findall(hlo)
     kernels = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(kernels) == 8
+    assert len(kernels) == 9
     assert all("/while/body/" in line for line in kernels)
+    assert sum("/tmix/wkv/" in line and "wkv_chunk" in line
+               for line in kernels) == 1
+
+
+def test_rwkv6_train_step_compiles_for_v5e_through_the_kernels(
+        one_chip, kernels_for_tpu):
+    """A one-chip train step (the model at its reduced size): the WKV
+    kernel runs forward, and again where the layer's remat recomputes
+    it, and its custom VJP takes XLA's chunked form backward."""
+    from repro.configs import get_config, reduced
+    from repro.models import rwkv6
+    from repro.models.params import ParamDef
+    from repro.optim import adamw_init, warmup_cosine
+    from repro.runtime import build_train_step
+
+    cfg = reduced(get_config("rwkv6-1.6b"))
+    params = jax.tree.map(
+        lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32,
+                                       sharding=one_chip),
+        rwkv6.param_defs(cfg), is_leaf=lambda d: isinstance(d, ParamDef))
+    opt = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(adamw_init, params))
+    tokens = jax.ShapeDtypeStruct((1, 64), jnp.int32, sharding=one_chip)
+    step = build_train_step(cfg, lr_schedule=warmup_cosine(1e-3, 2, 10))
+    hlo = jax.jit(step).lower(params, opt, {"tokens": tokens,
+                                            "labels": tokens}
+                              ).compile().as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("wkv_chunk" in line for line in kernels) == 2
 
 
 def test_granite_prefill_compiles_for_v5e_and_fits_one_chip(
