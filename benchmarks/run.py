@@ -1,6 +1,7 @@
 """Benchmark aggregator: one section per paper table/figure + the
-auto-scheduler DSE + kernels + (if dry-run artifacts exist) the TPU
-roofline summary.
+auto-scheduler's DSE, spatial, scan, perf, obs, serve and check rows.
+These are cost-model and host-side search numbers, never device
+metrics: device numbers come from ``bench/run.py`` on the chip.
 
 Prints ``name,value,derived`` CSV to stdout and mirrors the same rows
 into a machine-readable ``BENCH_<sha>.json`` under ``--out-dir``
@@ -36,7 +37,6 @@ def _git_sha() -> str:
 def collect_rows() -> list:
     """All benchmark rows as (name, value, note) tuples."""
     from benchmarks.paper_figs import ALL
-    from benchmarks.bench_kernels import bench_kernels
     from benchmarks.dse import (bench_obs, bench_scan, bench_search,
                                 bench_search_perf, bench_spatial)
     from benchmarks.serve import bench_serve
@@ -58,34 +58,6 @@ def collect_rows() -> list:
         dt = (time.perf_counter() - t0) * 1e6
         rows.append((f"_section.{section}.us_per_call", dt, ""))
 
-    t0 = time.perf_counter()
-    for name, value, note in bench_kernels():
-        rows.append((name, value, note))
-    dt = (time.perf_counter() - t0) * 1e6
-    rows.append(("_section.kernels.us_per_call", dt, ""))
-
-    # roofline summaries from dry-run artifacts (if present)
-    try:
-        from benchmarks import roofline
-        for tag, label in (("", "baseline"), ("opt", "optimized")):
-            rl = roofline.table("pod1", tag)
-            if not rl:
-                continue
-            for r in rl:
-                rows.append((
-                    f"roofline.{label}.{r['arch']}.{r['shape']}",
-                    r["roofline_fraction"],
-                    f"bound={r['bound']} mfu={r.get('mfu_proxy', 0):.4f}"))
-            for kind in ("train_4k", "prefill_32k", "decode_32k",
-                         "long_500k"):
-                sub = [x for x in rl if x["shape"] == kind]
-                if sub:
-                    avg = sum(x["roofline_fraction"] for x in sub) / len(sub)
-                    mfu = sum(x.get("mfu_proxy", 0) for x in sub) / len(sub)
-                    rows.append((f"roofline.{label}.mean.{kind}", avg,
-                                 f"mfu={mfu:.4f} n={len(sub)} cells"))
-    except Exception as e:                                # noqa: BLE001
-        rows.append(("_roofline.skipped", 0, str(e)))
     return rows
 
 

@@ -2,7 +2,7 @@
 
 Every assigned architecture is expressed as a frozen ``ModelConfig``; every
 assigned input shape as a ``ShapeConfig``.  Configs are plain data — models,
-launchers and the dry-run all consume them.  ``reduced()`` derives the small
+launchers and tests all consume them.  ``reduced()`` derives the small
 smoke-test variant of any config (same family, tiny dims).
 """
 from __future__ import annotations

@@ -1,33 +1,31 @@
 """Production mesh builders.
 
 Defined as FUNCTIONS (never module-level constants) so importing this
-module never touches jax device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
-init, and smoke tests must keep seeing 1 device.  Every axis is
-``AxisType.Auto``: the sharding rules in ``runtime.sharding`` place
-arrays with ``NamedSharding`` and let the compiler propagate the rest.
+module never touches jax device state: a process that wants placeholder
+devices sets ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+before first jax init, and smoke tests must keep seeing 1 device.  Every
+axis is ``AxisType.Auto``: the sharding rules in ``runtime.sharding``
+place arrays with ``NamedSharding`` and let the compiler propagate the
+rest.
 """
 from __future__ import annotations
 
 import jax
-import numpy as np
 from jax.sharding import AxisType, Mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """Single pod: (data=16, model=16) = 256 chips on ICI.
-    Multi-pod:  (pod=2, data=16, model=16) = 512 chips, pod axis on DCN."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
+def make_production_mesh() -> Mesh:
+    """One pod: (data=16, model=16) = 256 chips on ICI."""
+    shape = (16, 16)
+    n = shape[0] * shape[1]
     devices = jax.devices()
     if len(devices) < n:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, found {len(devices)} — set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
-            "importing jax (launch/dryrun.py does this)")
-    return jax.make_mesh(shape, axes, devices=devices[:n],
-                         axis_types=(AxisType.Auto,) * len(axes))
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
+            "importing jax")
+    return jax.make_mesh(shape, ("data", "model"), devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_host_mesh(*, model: int = 1) -> Mesh:

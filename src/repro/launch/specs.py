@@ -1,5 +1,5 @@
-"""ShapeDtypeStruct stand-ins for every model input — the dry-run lowers
-against these (weak-type-correct, shardable, zero allocation)."""
+"""ShapeDtypeStruct stand-ins for every model input — compiles without
+a chip lower against these (weak-type-correct, shardable, zero allocation)."""
 from __future__ import annotations
 
 from typing import Any, Dict

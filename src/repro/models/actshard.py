@@ -4,12 +4,12 @@ GSPMD propagates weight shardings into activations; with a 2-D
 (FSDP x TP) weight sharding the embedding gather is ambiguous and the
 partitioner can pick batch-REPLICATED, d_model-SHARDED activations — which
 turns every residual-stream op into a full-batch collective (observed:
-13 GB all-gathers on the LM head in the olmo-1b dry run).  These helpers
+13 GB all-gathers on the LM head of olmo-1b on a 16x16 mesh).  These helpers
 pin the canonical activation layout [batch=dp, seq=None, d_model=None] at
 the few places that anchor propagation (embedding output, scan carry,
 final hidden, logits).
 
-The launcher/dry-run installs the mesh via ``set_mesh``; without it every
+The launcher installs the mesh via ``set_mesh``; without it every
 helper is a no-op, so tests and single-device examples are unaffected.
 """
 from __future__ import annotations

@@ -142,9 +142,8 @@ def mlp_apply(cfg: ModelConfig, params: Params, x: jax.Array,
 
     xs = (wi_t, wo_t, wg_t) if gated else (wi_t, wo_t)
     out0 = jnp.zeros(x.shape[:-1] + (wo.shape[-1],), dtype)
-    # fully unrolled: a nested while loop would be invisible to the
-    # dry-run's scan-trip cost correction (and XLA schedules the chunk
-    # sequence freely when it is straight-line code)
+    # fully unrolled: XLA schedules the chunk sequence freely when it
+    # is straight-line code
     out, _ = lax.scan(step, out0, xs, unroll=ibn_chunks)
     return out
 
@@ -183,7 +182,7 @@ def moe_apply_auto(cfg: ModelConfig, params: Params, x: jax.Array,
                    ) -> Tuple[jax.Array, jax.Array]:
     """Pick the shard-local (shard_map) MoE when a production mesh is
     installed — GSPMD partitions the data-dependent dispatch scatter
-    catastrophically (EXPERIMENTS.md §Perf) — else the plain pjit path."""
+    catastrophically — else the plain pjit path."""
     from repro.models import actshard, moe_sharded
     mesh = actshard.current_mesh()
     if mesh is not None and "model" in mesh.axis_names \

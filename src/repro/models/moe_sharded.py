@@ -1,5 +1,5 @@
 """Expert-parallel MoE with shard-local dispatch (hillclimb for the MoE
-collective storm — see EXPERIMENTS.md §Perf).
+collective storm).
 
 The pjit/GSPMD lowering of the capacity-based scatter dispatch re-shards
 the data-dependent scatter/gather to replicated: the qwen3 train cell
@@ -91,7 +91,7 @@ def _local_moe(cfg: ModelConfig, capacity_factor: float, tp: int,
     slot = jnp.where(keep, flat_e * capacity + pos, e_pad * capacity)
     # single scatter ([N*k, d] source): a k-sliced scatter loop was tried
     # and REFUTED — each .at[].set copies the [E*C, d] buffer (8x temp
-    # blow-up, see EXPERIMENTS.md SPerf iteration A2a)
+    # blow-up)
     token_idx = jnp.repeat(jnp.arange(n), k)
     buf = jnp.zeros((e_pad * capacity, d), dtype).at[slot].set(
         x[token_idx], mode="drop")

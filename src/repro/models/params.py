@@ -4,7 +4,7 @@ Models declare their parameters as a pytree of ``ParamDef`` (shape, dtype,
 logical axes, initializer).  From one definition tree we derive:
 
 - ``init_params``     : materialized arrays (smoke tests / real training)
-- ``abstract_params`` : ``jax.ShapeDtypeStruct`` stand-ins (dry-run, no alloc)
+- ``abstract_params`` : ``jax.ShapeDtypeStruct`` stand-ins (compiles, no alloc)
 - ``param_pspecs``    : ``PartitionSpec`` per leaf via logical→mesh axis rules
 
 Logical axis names used across the model zoo:

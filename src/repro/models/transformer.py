@@ -1,8 +1,8 @@
 """Generic decoder-only TransformerLM (dense / MoE / VLM / SWA).
 
 Layers are stacked along a leading ``layers`` axis and executed with
-``lax.scan`` — this keeps the HLO size O(1) in depth (critical for the 512-
-device dry-run compiles) and is what enables XLA to overlap the FSDP weight
+``lax.scan`` — this keeps the HLO size O(1) in depth (critical for compiles
+on a large mesh) and is what enables XLA to overlap the FSDP weight
 all-gathers of layer i+1 with the compute of layer i.
 
 Entry points:

@@ -14,7 +14,7 @@ equivalence with sequential layer execution and gradient flow).
 
 Why not a default profile: at 16 stages the bubble + per-microbatch
 collective latency loses to FSDP for every assigned arch that fits in
-HBM (all of them — see EXPERIMENTS.md §Perf B1); PP becomes the right
+HBM (all of them); PP becomes the right
 tool when layer weights exceed a chip (≫15B dense at f32) — the
 mechanism is here for that regime.
 """

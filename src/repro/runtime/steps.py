@@ -1,7 +1,7 @@
 """train / prefill / decode step builders.
 
 Each builder returns a pure function suitable for ``jax.jit`` with explicit
-in/out shardings (the launcher and the dry-run both consume these).  The
+in/out shardings (the launchers and the compile tests consume these).  The
 steps are model-family agnostic via the module registry.
 """
 from __future__ import annotations

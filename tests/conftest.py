@@ -1,5 +1,6 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests must see the real
-single-device CPU backend (the 512-device override is dryrun-only)."""
+single-device CPU backend; a test that needs more devices starts its
+own process with its own flags."""
 import jax
 import numpy as np
 import pytest

@@ -2,7 +2,7 @@
 
 For each of the 10 archs: one train step on CPU asserting output shapes +
 finite loss, and a prefill -> decode round trip.  The FULL configs are
-exercised only by the dry-run (ShapeDtypeStruct, no allocation).
+exercised only as ShapeDtypeStruct specs (no allocation).
 """
 import dataclasses
 

@@ -474,3 +474,61 @@ def test_stacked_proj_gradient_is_the_plain_projections():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-5, atol=3e-5)
     assert not np.asarray(got[1])[[0, 2]].any()
+
+
+# ---------------------------------------------------------------------------
+# every wrapper at its default blocks, at model-sized shapes
+# ---------------------------------------------------------------------------
+
+
+def _default_fused_ibn(ks):
+    # transformer-FFN-shaped: M x D x F = 512 x 256 x 1024
+    x = jax.random.normal(ks[0], (512, 256), jnp.float32)
+    w1 = jax.random.normal(ks[1], (256, 1024)) * 0.05
+    w2 = jax.random.normal(ks[2], (1024, 256)) * 0.05
+    return (ops.fused_ibn(x, w1, w2), ref.fused_ibn_ref(x, w1, w2),
+            _tol(jnp.float32)["atol"])
+
+
+def _default_matmul_ln(ks):
+    x = jax.random.normal(ks[0], (512, 256), jnp.float32)
+    w = jax.random.normal(ks[1], (256, 256)) * 0.05
+    b, g, be = jnp.zeros((256,)), jnp.ones((256,)), jnp.zeros((256,))
+    return (ops.matmul_ln(x, w, b, g, be), ref.matmul_ln_ref(x, w, b, g, be),
+            _tol(jnp.float32)["atol"])
+
+
+def _default_flash_attention(ks):
+    q, k, v = (jax.random.normal(kk, (1, 4, 256, 64)) for kk in ks[:3])
+    return ops.flash_attention(q, k, v), ref.attention_ref(q, k, v), 2e-4
+
+
+def _default_depthwise_conv2d(ks):
+    # EdgeNeXt stage-3-shaped: 16 x 16 x 160, kernel 7
+    x = jax.random.normal(ks[0], (1, 16, 16, 160))
+    w = jax.random.normal(ks[1], (7, 7, 160)) * 0.1
+    b = jnp.zeros((160,))
+    return (ops.depthwise_conv2d(x, w, b),
+            ref.depthwise_conv2d_ref(x, w, b), 1e-4)
+
+
+def _default_wkv_chunked(ks):
+    # BH x T x K = 8 x 128 x 64: two chunks of the default 64
+    r, k, v = (jax.random.normal(kk, (8, 128, 64)) * 0.5 for kk in ks[:3])
+    logw = -jnp.exp(jax.random.normal(ks[3], (8, 128, 64)))
+    u = jax.random.normal(ks[4], (8, 64)) * 0.5
+    return (ops.wkv_chunked(r, k, v, logw, u)[0],
+            ref.wkv_ref(r, k, v, logw, u)[0], 2e-4)
+
+
+@pytest.mark.parametrize("case", [
+    _default_fused_ibn, _default_matmul_ln, _default_flash_attention,
+    _default_depthwise_conv2d, _default_wkv_chunked,
+], ids=["fused_ibn", "matmul_ln", "flash_attention", "depthwise_conv2d",
+        "wkv_chunked"])
+def test_kernel_at_its_default_blocks(case):
+    """Each ops wrapper called with no blocks, so its defaults set the
+    grid; tolerances are those of the kernel's sweep above."""
+    out, want, tol = case(jax.random.split(KEY, 5))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=tol, atol=tol)

@@ -459,14 +459,3 @@ def test_cli_dse_trace_nests_autos(tmp_path):
     assert len(dse) == 1 and len(autos) >= 2
     lo, hi = dse[0]["ts"], dse[0]["ts"] + dse[0]["dur"]
     assert all(lo <= a["ts"] <= hi for a in autos)
-
-
-def test_diagnose_import_is_side_effect_free():
-    r = subprocess.run(
-        [sys.executable, "-c",
-         "import os; snap = dict(os.environ); "
-         "import benchmarks.diagnose; "
-         "assert dict(os.environ) == snap, 'import mutated os.environ'"],
-        capture_output=True, text=True, timeout=60,
-        env={**ENV, "PYTHONPATH": "src:."}, cwd="/root/repo")
-    assert r.returncode == 0, r.stderr[-2000:]

@@ -1,4 +1,4 @@
-"""Input/cache spec structure for every dry-run cell + decode-vs-forward
+"""Input/cache spec structure for every (arch, shape) cell + decode-vs-forward
 consistency for the stateful families (hybrid, enc-dec)."""
 
 import jax

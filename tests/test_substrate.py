@@ -1,5 +1,5 @@
 """Substrate tests: checkpoint store, data pipeline, optimizer,
-compression, watchdog, HLO parser, sharding rules."""
+compression, watchdog, sharding rules."""
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +9,6 @@ import pytest
 from repro.checkpoint import (AsyncCheckpointer, latest_step,
                               load_checkpoint, save_checkpoint)
 from repro.configs import ARCHS, get_config
-from repro.core import hloanalysis
 from repro.data.synthetic import SyntheticLMDataset
 from repro.models import get_module, params as param_lib
 from repro.optim import (adamw_init, adamw_update, clip_by_global_norm,
@@ -181,41 +180,6 @@ def test_watchdog_ignores_warmup():
     wd = StragglerWatchdog(warmup_steps=2, threshold=2.0)
     assert not wd.record(0, 100.0)      # compile step
     assert not wd.record(1, 100.0)
-
-
-# ---------------------------------------------------------------------------
-# HLO collective parser
-# ---------------------------------------------------------------------------
-
-
-HLO_SAMPLE = """
-  %ag = bf16[16,1024]{1,0} all-gather(bf16[16,64]{1,0} %p0), dimensions={1}
-  %ar = f32[256,128]{1,0} all-reduce(f32[256,128]{1,0} %x), to_apply=%add
-  %rs = f32[16,8]{1,0} reduce-scatter(f32[16,128]{1,0} %y), dimensions={1}
-  %done = bf16[4]{0} all-gather-done(bf16[4]{0} %h)
-"""
-
-
-def test_parse_collectives_counts_and_bytes():
-    stats = hloanalysis.parse_collectives(HLO_SAMPLE)
-    assert stats["all-gather"].count == 1
-    assert stats["all-gather"].result_bytes == 16 * 1024 * 2
-    assert stats["all-reduce"].result_bytes == 256 * 128 * 4
-    # reduce-scatter wire bytes use the (bigger) operand
-    assert stats["reduce-scatter"].wire_bytes("reduce-scatter") == \
-        16 * 128 * 4
-    # all-reduce wire = 2x
-    assert stats["all-reduce"].wire_bytes("all-reduce") == 2 * 256 * 128 * 4
-
-
-def test_roofline_terms():
-    r = hloanalysis.Roofline(flops_per_device=197e12,
-                             hbm_bytes_per_device=819e9 / 2,
-                             collective_bytes_per_device=0.0)
-    assert r.compute_s == pytest.approx(1.0)
-    assert r.memory_s == pytest.approx(0.5)
-    assert r.bound == "compute"
-    assert r.roofline_fraction == pytest.approx(1.0)
 
 
 # ---------------------------------------------------------------------------
