@@ -59,7 +59,8 @@ class Layer:
     ibn_id: int = -1                 # groups the three IBN layers
     # SCAN only, the recurrence: "wkv" (per-channel decay with a bonus,
     # ``rwkv_chunk``'s; the RG-LRU is lowered as one too, ROADMAP 2.3)
-    # or "ssd" (Mamba-2: a scalar decay per head, no kernel yet)
+    # or "ssd" (Mamba-2: a scalar decay per head; ``ssd_chunk``'s, which
+    # the lowering does not target yet)
     scan_kind: str = "wkv"
 
     @property

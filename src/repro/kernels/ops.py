@@ -23,7 +23,8 @@ from jax import lax
 
 from repro.kernels import (depthwise_conv as _dw, flash_attention as _fa,
                            fused_ibn as _ibn, matmul_ln as _mln,
-                           rwkv_chunk as _wkv, stacked_proj as _sp)
+                           rwkv_chunk as _wkv, ssd_chunk as _ssd,
+                           stacked_proj as _sp)
 
 
 _LANE = 128      # TPU lane width: the last block dim's granularity
@@ -233,3 +234,33 @@ def wkv(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
         sub=_WKV_SUB, interpret=interp)
     return (out.reshape(B, T, H, K),
             _wkv.state_from_groups(groups, K))
+
+
+# the SSD kernel's step, chosen on a v5e at Granite 4.0-H Micro's
+# 8192-token prefill (tools/tune_ssd.py, PERF.md section 6): 16 heads a
+# step, sub-chunks of 64.  All 64 heads a step run 6% faster but take
+# ~4 s more of Mosaic compiling in set-up; sub-chunks of 16 or 32 and
+# the elementwise form (256) are slower at every step.
+_SSD_HEADS, _SSD_SUB = 16, 64
+
+
+def ssd(xbc: jax.Array, dt: jax.Array, A: jax.Array, D: jax.Array,
+        state: Optional[jax.Array] = None, *, d_state: int, chunk: int,
+        interpret: Optional[bool] = None):
+    """Mamba-2's chunked SSD with the D skip, in the model's layout:
+    xbc [b, T, H*P + 2N] the conv's output (xs | B | C), read in place;
+    dt [b, T, H] after softplus; A (< 0), D: [H]; state [b, H, P, N]
+    entering, or None (zeros).  Returns (y [b, T, H*P] f32, final state
+    [b, H, P, N] f32).  The chunk is Q verbatim (``ssd_chunk.ssd``)."""
+    interp = (not _on_tpu()) if interpret is None else interpret
+    H = dt.shape[-1]
+    P = (xbc.shape[-1] - 2 * d_state) // H
+    heads = _SSD_HEADS
+    if H % heads or (heads * P) % _LANE:
+        heads = H
+    if state is not None:
+        state = _ssd.state_to_blocks(state.astype(jnp.float32), heads)
+    y, state = _ssd.ssd(xbc, dt, A, D, state, d_state=d_state, chunk=chunk,
+                        heads_per_step=heads, sub=_SSD_SUB,
+                        interpret=interp)
+    return y, _ssd.state_from_blocks(state, P)

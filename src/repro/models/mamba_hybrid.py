@@ -20,15 +20,21 @@
 One B/C group (``mamba_n_groups`` 1) serves every head, as in the
 released model; the module takes no other.
 
-TPU adaptation: the SSD runs in chunked form (``ssd_chunked``).  Within
-a chunk of Q tokens the decay is a scalar per head, so the intra-chunk
-term is ``(C B^T) * exp(segsum(dt A))`` — one [Q, Q] product shared by
-all heads and a [H, Q, Q] decay — applied to ``dt * xs`` on the MXU;
-the [H, head_dim, d_state] state is carried across chunks by a scan.
+TPU adaptation: the SSD runs in chunked form.  Within a chunk of Q
+tokens the decay is a scalar per head, so the intra-chunk term is
+``(C B^T) * exp(segsum(dt A))`` — one [Q, Q] product shared by all heads
+and a per-head [Q, Q] decay — applied to ``dt * xs`` on the MXU; the
+[H, head_dim, d_state] state is carried across chunks.  On one device
+this is the Pallas kernel ``kernels.ssd_chunk`` (``ssd``): it reads xs,
+B and C in place from the conv's output, keeps each head's state in
+VMEM across the chunks, builds a decay only for each sub-chunk's
+diagonal block and only in VMEM, and writes y with the ``D * xs`` skip
+in the layout the gated norm reads.  Under an ``actshard`` mesh it is
+XLA's ``ssd_chunked`` (a [H, Q, Q] decay, the state carried by a scan).
 Every exponent is a difference of running sums of ``dt A <= 0`` taken
 later-minus-earlier and clipped at 0, so no positive number is
 exponentiated.  Decays, states and sums are f32; matmul operands are in
-the compute dtype.
+the compute dtype.  Decode (one token with a state) is ``ssd_step``.
 
 Layout: the weights are stacked per kind, ``mamba`` [n_mamba, ...],
 ``attn`` [n_attn, ...] and ``mlp`` [num_layers, ...], and the layers run
@@ -49,6 +55,7 @@ attention opens the middle three), ``res1``, ``ln2``, ``mlp`` / ``up``
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -212,6 +219,54 @@ def ssd_step(x, dt, A, B, C, state):
     return jnp.einsum("bhpi,bi->bhp", S, C.astype(f32)), S
 
 
+def ssd(xbc, dt, A, D, state, sizes: Tuple[int, int, int, int]):
+    """The chunked SSD with the ``D * xs`` skip, on the conv's output
+    ``xbc`` [b, T, H*P + 2N] (xs | B | C); dt [b, T, H] after softplus;
+    A, D: [H]; ``sizes`` (H, P, N, chunk).  Returns (y [b, T, H*P] f32,
+    final state [b, H, P, N] f32).  On one device the Pallas kernel
+    ``kernels.ssd_chunk``, which reads xs, B and C in place and keeps each
+    head's state in VMEM across the chunks; its gradient is that of
+    ``ssd_xla``.  Under an ``actshard`` mesh it is ``ssd_xla``: the
+    partitioner cannot split a Pallas call."""
+    if actshard.current_mesh() is not None:
+        return ssd_xla(xbc, dt, A, D, state, sizes)
+    return _ssd_kernel(xbc, dt, A, D, state, sizes)
+
+
+def ssd_xla(xbc, dt, A, D, state, sizes: Tuple[int, int, int, int]):
+    """``ssd`` as XLA's ``ssd_chunked``."""
+    H, P, N, chunk = sizes
+    b, T, _ = xbc.shape
+    di = H * P
+    xs = xbc[..., :di].reshape(b, T, H, P)
+    y, S = ssd_chunked(xs, dt, A, xbc[..., di:di + N], xbc[..., di + N:],
+                       chunk, state)
+    y = y + D[:, None] * xs.astype(jnp.float32)
+    return y.reshape(b, T, di), S
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _ssd_kernel(xbc, dt, A, D, state, sizes):
+    # imported where the SSD is traced, as in ``_proj``
+    from repro.kernels import ops
+    _, _, N, chunk = sizes
+    return ops.ssd(xbc, dt, A, D, state, d_state=N, chunk=chunk)
+
+
+def _ssd_kernel_fwd(xbc, dt, A, D, state, sizes):
+    return (_ssd_kernel(xbc, dt, A, D, state, sizes),
+            (xbc, dt, A, D, state))
+
+
+def _ssd_kernel_bwd(sizes, res, g):
+    """The XLA form's gradient, its forward recomputed."""
+    _, vjp = jax.vjp(lambda *a: ssd_xla(*a, sizes), *res)
+    return vjp(g)
+
+
+_ssd_kernel.defvjp(_ssd_kernel_fwd, _ssd_kernel_bwd)
+
+
 # ---------------------------------------------------------------------------
 # Layers
 # ---------------------------------------------------------------------------
@@ -255,18 +310,17 @@ def mamba_mixer(cfg: ModelConfig, p: Params, i, x: jax.Array, state=None):
                                         None if state is None else state[0])
         xbc = jax.nn.silu(xbc)
     with jax.named_scope("ssd"):
-        xs = xbc[..., :di].reshape(b, T, H, P)
-        Bm, Cm = xbc[..., di:di + N], xbc[..., di + N:]
         dt = jax.nn.softplus(dt.astype(f32) + _at(p["dt_bias"], i).astype(f32))
         A = -jnp.exp(_at(p["A_log"], i).astype(f32))
+        D = _at(p["D"], i).astype(f32)
         if T == 1 and state is not None:
-            y, ssm = ssd_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
-                              state[1])
-            y = y[:, None]
+            xs = xbc[:, 0, :di].reshape(b, H, P)
+            y, ssm = ssd_step(xs, dt[:, 0], A, xbc[:, 0, di:di + N],
+                              xbc[:, 0, di + N:], state[1])
+            y = y + D[:, None] * xs.astype(f32)
         else:
-            y, ssm = ssd_chunked(xs, dt, A, Bm, Cm, cfg.mamba_chunk_size,
-                                 None if state is None else state[1])
-        y = y + _at(p["D"], i).astype(f32)[:, None] * xs.astype(f32)
+            y, ssm = ssd(xbc, dt, A, D, None if state is None else state[1],
+                         (H, P, N, cfg.mamba_chunk_size))
     with jax.named_scope("gnorm"):
         y = y.reshape(b, T, di) * jax.nn.silu(z.astype(f32))
         y = _rms(cfg, _at(p["gnorm"]["scale"], i), y).astype(x.dtype)

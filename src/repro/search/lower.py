@@ -156,8 +156,10 @@ def lower_attention(qk: Layer, *, tile_x: int,
 def lower_scan(scan: Layer, tinfo: Dict[str, int]
                ) -> Optional[LoweredKernel]:
     """Chunked-recurrence layer -> rwkv_chunk(chunk), for a scan of kind
-    "wkv" only: no kernel computes Mamba-2's scalar-decay SSD ("ssd"), so
-    it lowers to nothing and counts as unlowered.  The searched chunk
+    "wkv" only: Mamba-2's scalar-decay SSD ("ssd") has its kernel
+    (``kernels.ssd_chunk``, which the model calls directly), but no
+    lowering rule targets it yet, so it lowers to nothing and counts as
+    unlowered.  The searched chunk
     length IS the kernel's sequence block.  Unlike the GEMM kernels the
     chunk is not re-snapped here — the search already restricted itself
     to the pow2 chunk menu, and the carry makes the grid order

@@ -418,6 +418,116 @@ def test_wkv_kernel_gradient_is_the_xla_forms():
 
 
 # ---------------------------------------------------------------------------
+# Mamba-2's chunked SSD (mamba_hybrid), the conv's output read in place
+# ---------------------------------------------------------------------------
+
+
+def _ssd_inputs(b, T, H, P, N, dtype, decay, key=7):
+    """xbc (xs | B | C, dtype), dt (f32, after softplus), A, D, and a
+    non-zero entering state [b, H, P, N]; ``decay`` sets dt*A's mean."""
+    ks = jax.random.split(jax.random.PRNGKey(key), 5)
+    xbc = jax.random.normal(ks[0], (b, T, H * P + 2 * N)).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, T, H)))
+    A = -decay * jnp.exp(0.2 * jax.random.normal(ks[2], (H,))) \
+        / jnp.mean(dt)
+    D = jax.random.uniform(ks[3], (H,), minval=0.5, maxval=1.5)
+    state = jax.random.normal(ks[4], (b, H, P, N)) * 0.3
+    return xbc, dt, A, D, state
+
+
+def _ssd_recurrence(xbc, dt, A, D, state, H, P, N):
+    """The token-by-token recurrence (``mamba_hybrid.ssd_step``) with
+    the skip, in f32: (y [b, T, H*P], final state)."""
+    from repro.models import mamba_hybrid
+    b, T, _ = xbc.shape
+    di = H * P
+    xbc = xbc.astype(jnp.float32)
+    xs = xbc[..., :di].reshape(b, T, H, P)
+
+    def step(S, inp):
+        x_t, dt_t, B_t, C_t = inp
+        y, S = mamba_hybrid.ssd_step(x_t, dt_t, A, B_t, C_t, S)
+        return S, y + D[:, None] * x_t
+
+    S, y = jax.lax.scan(step, state, tuple(a.swapaxes(0, 1) for a in (
+        xs, dt, xbc[..., di:di + N], xbc[..., di + N:])))
+    return y.swapaxes(0, 1).reshape(b, T, di), S
+
+
+@pytest.mark.parametrize(
+    "b,T,H,P,N,chunk,heads,sub,dtype,decay,entering,tol", [
+        # the reduced configuration's widths (4 heads of 32, d_state 16:
+        # xs, B and C sliced apart), T ragged at chunk 8, elementwise
+        (2, 37, 4, 32, 16, 8, 8, 64, jnp.float32, 0.5, True, 2e-4),
+        # T shorter than one chunk
+        (2, 5, 4, 32, 16, 8, 8, 64, jnp.float32, 1.0, False, 2e-4),
+        # several chunks of 16 in sub-chunks of 4, two heads of 64 a
+        # step (128 lanes, read in place from xbc with N = 128)
+        (1, 64, 4, 64, 128, 16, 2, 4, jnp.float32, 0.5, True, 2e-4),
+        # weak decay: the carried state matters across every chunk
+        (1, 64, 4, 64, 128, 32, 4, 8, jnp.float32, 0.05, True, 2e-4),
+        # strong decay: dt*A down past -100 within a chunk, so the
+        # sub-chunk factors underflow where the true decays do
+        (1, 64, 4, 64, 128, 32, 2, 8, jnp.float32, 8.0, True, 2e-4),
+        # bf16 xbc, as the model feeds it: one bf16 MXU pass, f32 sums
+        (1, 96, 4, 64, 128, 32, 2, 16, jnp.bfloat16, 0.5, True, 2e-2),
+        (2, 40, 4, 32, 16, 16, 8, 8, jnp.bfloat16, 1.0, False, 2e-2),
+    ], ids=["reduced-ragged", "shorter-than-a-chunk", "sub-chunks",
+            "weak-decay", "strong-decay", "bf16", "bf16-reduced"])
+def test_ssd_kernel_in_model_layout(b, T, H, P, N, chunk, heads, sub, dtype,
+                                    decay, entering, tol, monkeypatch):
+    """ops.ssd (the kernel on the conv's [b, T, H*P + 2N] output, from a
+    non-zero state or zeros) == the token-by-token recurrence == the
+    model's XLA chunked form, y with the D skip and the final state.
+    ``heads``, ``sub``: the grid step in place of the tuned one."""
+    from repro.models import mamba_hybrid
+    monkeypatch.setattr(ops, "_SSD_HEADS", heads)
+    monkeypatch.setattr(ops, "_SSD_SUB", sub)
+    xbc, dt, A, D, state = _ssd_inputs(b, T, H, P, N, dtype, decay)
+    if decay > 1:
+        assert float(jnp.cumsum(dt * A, axis=1).min()) < -100
+    state = state if entering else jnp.zeros_like(state)
+    y, st = ops.ssd(xbc, dt, A, D, state if entering else None,
+                    d_state=N, chunk=chunk)
+    assert y.dtype == st.dtype == jnp.float32
+    assert y.shape == (b, T, H * P) and st.shape == (b, H, P, N)
+    want, st_want = _ssd_recurrence(xbc, dt, A, D, state, H, P, N)
+    with jax.default_matmul_precision("highest"):
+        xla, st_xla = mamba_hybrid.ssd_xla(xbc.astype(jnp.float32), dt, A,
+                                           D, state, (H, P, N, chunk))
+    for got_y, got_s in ((y, st), (xla, st_xla)):
+        np.testing.assert_allclose(
+            np.asarray(got_y), np.asarray(want), rtol=tol,
+            atol=tol * float(jnp.abs(want).max()))
+        np.testing.assert_allclose(
+            np.asarray(got_s), np.asarray(st_want), rtol=tol,
+            atol=tol * float(jnp.abs(st_want).max()))
+
+
+def test_ssd_kernel_gradient_is_the_xla_forms():
+    """The model's kernel path differentiates as XLA's chunked form: its
+    custom VJP recomputes that form's gradient, the entering state's
+    too."""
+    from repro.models import mamba_hybrid
+    H, P, N, chunk = 4, 32, 16, 8
+    xbc, dt, A, D, state = _ssd_inputs(1, 20, H, P, N, jnp.float32, 0.5)
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    w_y = jax.random.normal(ks[0], (1, 20, H * P))
+    w_st = jax.random.normal(ks[1], state.shape)
+
+    def loss(f):
+        def run(*args):
+            y, st = f(*args, (H, P, N, chunk))
+            return (y * w_y).sum() + (st * w_st).sum()
+        return jax.grad(run, argnums=tuple(range(5)))(xbc, dt, A, D, state)
+
+    for got, want in zip(loss(mamba_hybrid._ssd_kernel),
+                         loss(mamba_hybrid.ssd_xla)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
 # projection by one layer of a stacked weight, cast in VMEM
 # ---------------------------------------------------------------------------
 

@@ -139,6 +139,8 @@ _SCORES = re.compile(r"= f32\[(?:\d+,)*64,64,64\]")
 # the weight stacks of the served RWKV-6 and their per-layer slices
 _STACK = re.compile(r"= bf16\[(?:24,|1,)?(?:2048,2048|2048,7168|7168,2048)\]"
                     r"\S* convert\(")
+# XLA's chunked SSD decay: an f32 [..., Q, Q] tensor at Granite's chunk 256
+_DECAY = re.compile(r"= f32\[(?:\d+,)*256,256\]")
 _SLICE = re.compile(r"= \w+\[(?:1,)?(?:2048,2048|2048,7168|7168,2048)\]"
                     r"\S* dynamic-slice\(")
 
@@ -217,8 +219,10 @@ def test_granite_prefill_compiles_for_v5e_and_fits_one_chip(
     """The served Granite 4.0-H Micro forward at full width and the
     cell's 8192 tokens (bf16 weights): every projection of the unrolled
     period (9 Mamba-2 x 4, one attention x 4, 10 MLPs x 2) is a
-    ``stacked_proj`` kernel in the layer scan, and weights plus
-    temporaries fit one v5e's 16 GB."""
+    ``stacked_proj`` kernel in the layer scan, every Mamba-2 layer's SSD
+    the ``ssd_chunk`` kernel in its ``ssd`` scope, so XLA builds no
+    [.., 256, 256] f32 decay, and weights plus temporaries fit one v5e's
+    16 GB."""
     from repro.configs.granite_4_0_h_micro import CONFIG
     from repro.models import mamba_hybrid
     from repro.models.params import ParamDef
@@ -235,12 +239,66 @@ def test_granite_prefill_compiles_for_v5e_and_fits_one_chip(
         return mamba_hybrid.logits_fn(CONFIG, p, hidden[:, -1:, :])
 
     compiled = jax.jit(served).lower(params, tokens).compile()
-    kernels = [line for line in compiled.as_text().splitlines()
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(kernels) == 60
+    ssd = [line for line in kernels if "ssd_chunk" in line]
+    assert len(kernels) - len(ssd) == 60
+    assert len(ssd) == 9 and all("/mamba/ssd/" in line for line in ssd)
     assert all("/while/body/" in line for line in kernels)
+    assert not _DECAY.findall(hlo)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
+def _granite_on_a_mesh(topo):
+    """The reduced Granite forward on a described 2x2 v5e, batch over
+    'data' and the projections over 'model': its optimized HLO."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import SHAPES_BY_NAME, get_config, reduced
+    from repro.launch.specs import input_specs
+    from repro.models import actshard, mamba_hybrid
+    from repro.models.params import ParamDef
+    from repro.runtime import batch_pspecs, model_param_pspecs
+
+    cfg = reduced(get_config("granite-4.0-h-micro"))
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    defs = mamba_hybrid.param_defs(cfg)
+    params = jax.tree.map(
+        lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32), defs,
+        is_leaf=lambda d: isinstance(d, ParamDef))
+    batch = input_specs(cfg, dataclasses.replace(
+        SHAPES_BY_NAME["train_4k"], seq_len=32, global_batch=4))
+
+    def named(tree):
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
+                            is_leaf=lambda s: isinstance(s, P))
+
+    actshard.set_mesh(mesh, "tp")
+    try:
+        fwd = jax.jit(
+            lambda p, b: mamba_hybrid.forward(cfg, p, b)[0],
+            in_shardings=(named(model_param_pspecs(cfg, mesh, defs,
+                                                   profile="tp")),
+                          named(batch_pspecs(cfg, mesh, batch, "tp"))))
+        return fwd.lower(params, batch).compile().as_text(), cfg
+    finally:
+        actshard.set_mesh(None)
+
+
+def test_granite_forward_on_a_v5e_mesh_keeps_xla_s_chunked_ssd(
+        topo, one_chip, kernels_for_tpu):
+    """Under an ``actshard`` mesh the partitioner cannot split a Pallas
+    call, so Granite keeps XLA's ``ssd_chunked`` there: no kernel, and
+    the intra-chunk decay is XLA's [.., chunk, chunk] f32 tensor."""
+    hlo, cfg = _granite_on_a_mesh(topo)
+    Q = cfg.mamba_chunk_size
+    assert "tpu_custom_call" not in hlo
+    assert re.search(rf"= f32\[(?:\d+,)*{Q},{Q}\]", hlo)
 
 
 def test_stacked_proj_compiles_for_v5e_at_decodes_one_row(one_chip):
